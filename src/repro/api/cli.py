@@ -15,6 +15,8 @@ Plan IR persistence (docs/api.md "Plan IR & replay"):
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 from typing import List, Optional
 
 from ..core.scheduler import load_plans, save_plans
@@ -22,6 +24,26 @@ from .cluster import ClusterSpec
 from .engine import Engine, StepMetrics
 from .strategies import (ReplayStrategy, available_strategies,
                          get_strategy)
+
+
+#: where entry points keep JAX's persistent compilation cache unless
+#: JAX_COMPILATION_CACHE_DIR names another place. A fixed path inside
+#: the checkout: the cache is keyed on it, so a path that moved between
+#: runs would never hit.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for this process.
+
+    Entry points call this before their first compile; importing the
+    library never does, so the tests write no cache. When
+    JAX_COMPILATION_CACHE_DIR is set, JAX already reads it and nothing
+    else is set here."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -152,6 +174,7 @@ def main(argv: Optional[List[str]] = None, *,
         for name in available_strategies():
             print(name)
         return
+    use_compile_cache()
     run(args, default_strategy)
 
 
@@ -202,6 +225,7 @@ def serve_main(argv: Optional[List[str]] = None) -> None:
     from ..serving.trace import sample_trace
 
     args = build_serve_parser().parse_args(argv)
+    use_compile_cache()
     engine = Engine(args.arch, ClusterSpec.auto(),
                     strategy=args.strategy, reduced=args.reduced,
                     seed=args.seed)

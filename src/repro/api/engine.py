@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from functools import partial
 from typing import Any, Dict, Iterable, Iterator, List, Optional, Union
 
 from ..configs import get_config
@@ -265,13 +266,19 @@ class Engine:
                 from ..training.train_step import TrainState
                 opt = self.optimizer
 
-                @jax.jit
+                # donating the state lets the new state reuse its
+                # buffers: without it the old and new params + fp32
+                # moments are live at once, which does not fit one
+                # 16 GB chip at internvl3-2b widths
+                @partial(jax.jit, donate_argnums=0)
                 def apply_update(state, grads):
                     p, o = opt.update(grads, state.opt, state.params)
                     return TrainState(p, o)
 
                 self._apply_update = apply_update
             self.state = self._apply_update(self.state, grads)
+        # the step ends when the device has the new state
+        jax.block_until_ready(self.state if update else grads)
         step_time = time.perf_counter() - t0
         model_error = 0.0
         if timings:

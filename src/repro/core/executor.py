@@ -59,7 +59,6 @@ from ..configs.base import ModelConfig
 from ..data.pipeline import RaggedBatch, padded_batch
 from ..models.model import forward
 from ..obs.trace import get_tracer
-from ..parallel.compat import shard_map
 from ..training.optimizer import AdamW
 from .group_pool import GroupPool
 from .packing import MODALITY_CLASSES, flatten_group
@@ -171,7 +170,7 @@ class DHPExecutor:
             def loss_of(params, batch):
                 # params enter shard_map replicated (demo TP=1)
                 out_specs = (P(), P()) if with_spans else P()
-                return shard_map(
+                return jax.shard_map(
                     shard_loss, mesh=mesh,
                     in_specs=(pspec, bspec), out_specs=out_specs,
                 )(params, batch)

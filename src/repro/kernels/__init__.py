@@ -1,3 +1,20 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels (flash attention, SSD chunk, RG-LRU scan), their
+pure-jnp oracles (`ref.py`) and the model-layout wrappers (`ops.py`)."""
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """Whether a Pallas call runs in the interpreter.
+
+    `None` (every wrapper's default) decides from the backend: interpret
+    only on the CPU backend, which has no Mosaic compiler. On a TPU the
+    kernel compiles or raises; it never falls back silently. An explicit
+    bool wins — an ahead-of-time compile for a described TPU from a CPU
+    process passes `False`."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() == "cpu"

@@ -28,7 +28,7 @@ term of Eq. 8). TPU-native design, not a CUDA port:
     — the mask DHP's Eq. 8 eta factor costs (span ids -1 = causal).
 
 Validated against ref.flash_attention_ref / ref.flash_attention_packed_ref
-in interpret mode (CPU).
+in interpret mode on CPU; compiled by Mosaic on TPU (`interpret_mode`).
 """
 from __future__ import annotations
 
@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret_mode
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
@@ -148,23 +150,22 @@ def _packed_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref, *refs,
 
     q_start = qi * block_q
     k_start = kv_offset + ki * block_k
-    seg_q = segq_ref[0]                                  # [bq] int32
-    seg_k = segk_ref[0]                                  # [bk] int32
+    seg_q = segq_ref[0]                                  # [bq, 1] int32
+    seg_k = segk_ref[0]                                  # [1, bk] int32
     qpos = q_start + jax.lax.broadcasted_iota(jnp.int32,
                                               (block_q, block_k), 0)
     kpos = k_start + jax.lax.broadcasted_iota(jnp.int32,
                                               (block_q, block_k), 1)
     # same segment; padding (seg < 0) never attends or is attended
-    valid = (seg_q[:, None] == seg_k[None, :]) & (seg_q >= 0)[:, None]
+    valid = (seg_q == seg_k) & (seg_q >= 0)
     if mode != "full":
         ok = kpos <= qpos
         if mode == "sliding":
             ok &= kpos > qpos - window
         if has_spans:
-            span_q = spanq_ref[0]                        # [bq] int32
-            span_k = spank_ref[0]                        # [bk] int32
-            ok |= (span_q >= 0)[:, None] \
-                & (span_q[:, None] == span_k[None, :])
+            span_q = spanq_ref[0]                        # [bq, 1] int32
+            span_k = spank_ref[0]                        # [1, bk] int32
+            ok |= (span_q >= 0) & (span_q == span_k)
         valid &= ok
     # O(bq*bk) mask vs O(bq*bk*D) matmuls: deciding the skip costs 1/D
     # of the tile; fully-masked tiles (cross-segment, future-causal,
@@ -214,7 +215,8 @@ def flash_attention_packed_flat(q, k, v, segment_ids, *,
                                 block_q: int = DEFAULT_BLOCK_Q,
                                 block_k: int = DEFAULT_BLOCK_K,
                                 kv_offset: int = 0,
-                                interpret: bool = True) -> jax.Array:
+                                interpret: Optional[bool] = None
+                                ) -> jax.Array:
     """Packed variable-length flash attention.
 
     q: [BH, Sq, D]; k/v: [BH, Sk, D]; segment_ids: [Sq] or [BH, Sq]
@@ -246,8 +248,16 @@ def flash_attention_packed_flat(q, k, v, segment_ids, *,
             seg = jnp.broadcast_to(seg[None], (BH, length))
         return jnp.pad(seg, ((0, 0), (0, pad)), constant_values=fill)
 
-    segq = _norm_seg(segment_ids, Sq, pad_q, -1)         # [BH, Sq+pad]
-    segk = _norm_seg(kv_seg, Sk, pad_k, -2)              # [BH, Sk+pad]
+    # Query-side tables go in as columns [BH, Sq, 1] and key-side ones
+    # as rows [BH, 1, Sk]: Mosaic takes a block whose last two dims are
+    # (8k, 128k) or the full array dims, which (1, block) over [BH, S]
+    # is not; these give the kernel [bq, 1] x [1, bk] tiles that
+    # broadcast straight into the [bq, bk] mask.
+    def q_table(seg):
+        return _norm_seg(seg, Sq, pad_q, -1)[:, :, None]
+
+    def k_table(seg):
+        return _norm_seg(seg, Sk, pad_k, -2)[:, None, :]
     nq = (Sq + pad_q) // block_q
     nk = (Sk + pad_k) // block_k
 
@@ -256,21 +266,20 @@ def flash_attention_packed_flat(q, k, v, segment_ids, *,
         sm_scale=1.0 / math.sqrt(D), block_q=block_q, block_k=block_k,
         kv_offset=kv_offset, has_spans=has_spans)
 
-    q_spec = pl.BlockSpec((1, block_q), lambda b, i, j: (b, i))
-    k_spec = pl.BlockSpec((1, block_k), lambda b, i, j: (b, j))
+    q_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+    k_spec = pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j))
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
         pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
         q_spec, k_spec,
     ]
-    inputs = [qp, kp, vp, segq, segk]
+    inputs = [qp, kp, vp, q_table(segment_ids), k_table(kv_seg)]
     if has_spans:
         # span tables only enter the kernel when a layout exists —
         # span-free callers keep the exact pre-span kernel program
         in_specs += [q_spec, k_spec]
-        inputs += [_norm_seg(span_ids, Sq, pad_q, -1),
-                   _norm_seg(kv_span, Sk, pad_k, -2)]
+        inputs += [q_table(span_ids), k_table(kv_span)]
 
     out = pl.pallas_call(
         kernel,
@@ -283,7 +292,7 @@ def flash_attention_packed_flat(q, k, v, segment_ids, *,
             pltpu.VMEM((block_q,), jnp.float32),      # l
             pltpu.VMEM((block_q, D), jnp.float32),    # acc
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(*inputs)
     return out[:, :Sq]
 
@@ -297,12 +306,11 @@ def flash_attention_flat(q, k, v, *, mode: str = "causal",
                          block_q: int = DEFAULT_BLOCK_Q,
                          block_k: int = DEFAULT_BLOCK_K,
                          kv_offset: int = 0,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
     """q: [BH, Sq, D]; k/v: [BH, Sk, D] (KV pre-expanded to all heads).
 
-    `interpret=True` runs the kernel body on CPU (this container);
-    compile for real TPUs with interpret=False.
-    """
+    `interpret=None` interprets on the CPU backend and compiles
+    elsewhere (`kernels.interpret_mode`)."""
     BH, Sq, D = q.shape
     Sk = k.shape[1]
     pad_q = (-Sq) % block_q
@@ -332,6 +340,6 @@ def flash_attention_flat(q, k, v, *, mode: str = "causal",
             pltpu.VMEM((block_q,), jnp.float32),      # l
             pltpu.VMEM((block_q, D), jnp.float32),    # acc
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qp, kp, vp)
     return out[:, :Sq]

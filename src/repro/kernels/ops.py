@@ -31,7 +31,7 @@ def _expand_gqa(k: jax.Array, n_heads: int) -> jax.Array:
                           "block_k"))
 def flash_attention(q, k, v, *, mode: str = "causal",
                     window: Optional[int] = None, ref: bool = False,
-                    interpret: bool = True, block_q: int = 128,
+                    interpret: Optional[bool] = None, block_q: int = 128,
                     block_k: int = 128) -> jax.Array:
     """q: [B,S,H,D]; k/v: [B,S,Hkv,D] -> [B,S,H,D]."""
     B, Sq, H, D = q.shape
@@ -56,7 +56,8 @@ def flash_attention(q, k, v, *, mode: str = "causal",
 def flash_attention_packed(q, k, v, segment_ids, *, mode: str = "causal",
                           window: Optional[int] = None,
                           span_ids=None, ref: bool = False,
-                          interpret: bool = True, block_q: int = 128,
+                          interpret: Optional[bool] = None,
+                          block_q: int = 128,
                           block_k: int = 128) -> jax.Array:
     """Packed varlen attention in model layout.
 
@@ -115,7 +116,7 @@ def flash_attention_packed(q, k, v, segment_ids, *, mode: str = "causal",
 
 @partial(jax.jit, static_argnames=("ref", "interpret"))
 def ssd_chunk_scan(C, B, x, da, dt, *, ref: bool = False,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """Full chunked-SSD output for independent sequences of chunks.
 
     C, B: [G, nc, c, N]; x: [G, nc, c, P]; da, dt: [G, nc, c]

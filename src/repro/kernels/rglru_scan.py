@@ -13,11 +13,14 @@ and keep the carried state in VMEM scratch between grid steps.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret_mode
 
 
 def _kernel(a_ref, b_ref, h_ref, carry_scr, *, chunk: int):
@@ -50,7 +53,7 @@ def _kernel(a_ref, b_ref, h_ref, carry_scr, *, chunk: int):
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "interpret"))
 def rglru_scan_pallas(a, b, *, chunk: int = 128,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """a, b: [B, S, W] -> h: [B, S, W] with h_t = a_t h_{t-1} + b_t."""
     B, S, W = a.shape
     pad = (-S) % chunk
@@ -69,6 +72,6 @@ def rglru_scan_pallas(a, b, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, chunk, W), lambda bi, ci: (bi, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S + pad, W), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, W), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a, b)
     return out[:, :S]

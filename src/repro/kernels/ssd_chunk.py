@@ -24,10 +24,13 @@ Validated against `ref.ssd_chunk_ref` in interpret mode (CPU).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import interpret_mode
 
 
 def _kernel(c_ref, b_ref, x_ref, da_ref, dt_ref,
@@ -59,7 +62,8 @@ def _kernel(c_ref, b_ref, x_ref, da_ref, dt_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk_pallas(C, B, x, da, dt, *, interpret: bool = True):
+def ssd_chunk_pallas(C, B, x, da, dt, *,
+                     interpret: Optional[bool] = None):
     """Intra-chunk SSD for a batch of independent chunks.
 
     C, B: [G, c, N]; x: [G, c, P]; da, dt: [G, c]
@@ -88,5 +92,5 @@ def ssd_chunk_pallas(C, B, x, da, dt, *, interpret: bool = True):
             jax.ShapeDtypeStruct((G, N, P), jnp.float32),
             jax.ShapeDtypeStruct((G, c), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(C, B, x, da, dt)

@@ -24,8 +24,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from . import compat
-
 NEG_INF = -1e30
 
 
@@ -104,7 +102,7 @@ def ring_attention(q, k, v, q_pos, *, axis_name: str,
     Like segments and positions, the table rides every ppermute hop, so
     a block sharded across ranks stays bidirectional end to end.
     """
-    d = compat.axis_size(axis_name)
+    d = jax.lax.axis_size(axis_name)
     B, S, H, Dh = q.shape
     Hkv = k.shape[2]
     G = H // Hkv

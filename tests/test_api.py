@@ -231,3 +231,26 @@ def test_cli_list_strategies(capsys):
     out = capsys.readouterr().out.split()
     for name in ("static", "dhp", "bruteforce", "oracle"):
         assert name in out
+
+
+def test_use_compile_cache_placement(monkeypatch):
+    """Entry points keep the compile cache where JAX_COMPILATION_CACHE_DIR
+    says, else at one fixed, git-ignored path inside the checkout."""
+    from pathlib import Path
+
+    import jax
+    from repro.api import cli
+    root = Path(__file__).resolve().parents[1]
+    assert cli.COMPILE_CACHE_DIR == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        cli.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        cli.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == str(
+            cli.COMPILE_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -24,6 +24,14 @@ def qkv(B, S, H, Hkv, D, dtype=jnp.float32, Skv=None):
     return q, k, v
 
 
+def test_interpret_mode_follows_backend():
+    """Interpret only on the CPU backend; an explicit choice wins."""
+    from repro.kernels import interpret_mode
+    assert interpret_mode() == (jax.default_backend() == "cpu")
+    assert interpret_mode(False) is False
+    assert interpret_mode(True) is True
+
+
 # ------------------------------------------------------------- flash attn
 @pytest.mark.parametrize("mode,window", [("causal", None), ("full", None),
                                          ("sliding", 96)])

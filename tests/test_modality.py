@@ -222,7 +222,6 @@ def test_ring_span_table_rides_hops(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.parallel.compat import shard_map
 from repro.parallel.ring_attention import ring_attention
 from repro.models.attention import attn_reference
 
@@ -248,7 +247,7 @@ v = jax.random.normal(jax.random.fold_in(key,2),(B,S,Hkv,Dh))
 posj = jnp.asarray(pos)[None]
 segj = jnp.asarray(seg)[None]
 spanj = jnp.asarray(span)[None]
-fm = shard_map(
+fm = jax.shard_map(
     lambda q,k,v,p,s,sp: ring_attention(q,k,v,p,axis_name="cp",
                                         q_seg=s,q_span=sp),
     mesh=mesh, in_specs=(P(None,"cp"),)*6, out_specs=P(None,"cp"))

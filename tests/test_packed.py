@@ -331,7 +331,6 @@ def test_ring_packed_segments(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.parallel.compat import shard_map
 from repro.parallel.ring_attention import ring_attention
 from repro.models.attention import attn_reference
 
@@ -349,7 +348,7 @@ k = jax.random.normal(jax.random.fold_in(key,1),(B,S,Hkv,Dh))
 v = jax.random.normal(jax.random.fold_in(key,2),(B,S,Hkv,Dh))
 posj = jnp.asarray(pos)[None]
 segj = jnp.asarray(seg)[None]
-fm = shard_map(
+fm = jax.shard_map(
     lambda q,k,v,p,s: ring_attention(q,k,v,p,axis_name="cp",q_seg=s),
     mesh=mesh, in_specs=(P(None,"cp"),)*5, out_specs=P(None,"cp"))
 out = fm(q,k,v,posj,segj)

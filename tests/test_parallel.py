@@ -7,7 +7,6 @@ def test_ring_attention_non_power_of_two(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.parallel.compat import shard_map
 from repro.parallel.ring_attention import ring_attention
 from repro.models.attention import attn_reference
 
@@ -20,7 +19,7 @@ for d_cp in (3, 5, 6):
     k = jax.random.normal(jax.random.fold_in(key,1),(B,S,Hkv,Dh))
     v = jax.random.normal(jax.random.fold_in(key,2),(B,S,Hkv,Dh))
     pos = jnp.tile(jnp.arange(S)[None],(B,1))
-    fm = shard_map(
+    fm = jax.shard_map(
         lambda q,k,v,p: ring_attention(q,k,v,p,axis_name="cp"),
         mesh=mesh,
         in_specs=(P(None,"cp"),)*4, out_specs=P(None,"cp"))
@@ -36,7 +35,6 @@ def test_ring_attention_gradients(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.parallel.compat import shard_map
 from repro.parallel.ring_attention import ring_attention
 from repro.models.attention import attn_reference
 
@@ -48,7 +46,7 @@ q = jax.random.normal(key,(B,S,H,Dh))
 k = jax.random.normal(jax.random.fold_in(key,1),(B,S,Hkv,Dh))
 v = jax.random.normal(jax.random.fold_in(key,2),(B,S,Hkv,Dh))
 pos = jnp.tile(jnp.arange(S)[None],(B,1))
-fm = shard_map(
+fm = jax.shard_map(
     lambda q,k,v,p: ring_attention(q,k,v,p,axis_name="cp"),
     mesh=mesh, in_specs=(P(None,"cp"),)*4, out_specs=P(None,"cp"))
 g1 = jax.grad(lambda q,k,v: (fm(q,k,v,pos)**2).sum(), argnums=(0,1,2))(q,k,v)
@@ -64,7 +62,6 @@ def test_ring_decode_distributed_softmax(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.parallel.compat import shard_map
 from repro.parallel.ring_attention import ring_decode_attention
 from repro.models.attention import attn_decode
 devs = jax.devices()
@@ -74,7 +71,7 @@ key = jax.random.PRNGKey(1)
 q1 = jax.random.normal(key,(B,1,H,Dh))
 kc = jax.random.normal(jax.random.fold_in(key,1),(B,T,Hkv,Dh))
 vc = jax.random.normal(jax.random.fold_in(key,2),(B,T,Hkv,Dh))
-gm = shard_map(
+gm = jax.shard_map(
     lambda q1,kc,vc: ring_decode_attention(
         q1,kc,vc,jnp.full((q1.shape[0],), kc.shape[1]),axis_name="cp"),
     mesh=mesh, in_specs=(P(),P(None,"cp"),P(None,"cp")), out_specs=P())
