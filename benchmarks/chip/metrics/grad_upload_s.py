@@ -1,0 +1,18 @@
+"""Seconds per window step the averaged float32 gradients take to go
+back to the device, waited for, after the executor's `collect` loop
+(`grad_upload` spans).
+
+None when the run holds no span of the executor's split of `collect`
+(tracing off, or a program from before the split); 0.0 when the split
+is there and this part is not."""
+
+SPAN = "grad_upload"
+SPLIT = ("collect.wait", "collect.d2h", "collect.sum", "grad_upload")
+
+
+def read(run):
+    spans = run["spans"]
+    if not any(s["name"] in SPLIT for s in spans):
+        return None
+    return (sum(s["dur_s"] for s in spans if s["name"] == SPAN)
+            / len(run["steps"]))

@@ -85,7 +85,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--trace", metavar="PATH", default=None,
                     help="write a Chrome trace-event JSON timeline of "
                     "the run to PATH (open at https://ui.perfetto.dev); "
-                    "switches execution to measuring mode")
+                    "groups keep their concurrent dispatch")
     ap.add_argument("--report", metavar="PATH", default=None,
                     help="write the post-run analytics report "
                     "(imbalance, stragglers, cost-model MAPE) to PATH "
@@ -138,7 +138,7 @@ def run(args, default_strategy: str = "dhp") -> List[StepMetrics]:
         global_batch=args.batch, max_tokens=args.seq_len,
         lookahead=not getattr(args, "no_lookahead", False),
         plan_log=plan_log, log=print,
-        trace=trace, report=report or bool(trace))
+        trace=trace, report=report)
     print("executable pool:", engine.executor.pool.stats)
     cache = engine.strategy.plan_cache
     if cache is not None:

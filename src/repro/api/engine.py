@@ -35,6 +35,11 @@ from .strategies import Strategy, get_strategy
 
 Batch = Union[RaggedBatch, List[SeqInfo]]
 
+#: XLA module name of the optimizer update (`apply_update` in
+#: `Engine.update_fn`): the chip benchmark's device-trace readers find
+#: the program by this name
+UPDATE_MODULE = "jit_apply_update"
+
 
 @dataclasses.dataclass
 class StepMetrics:
@@ -186,9 +191,9 @@ class Engine:
         #: updated by every execute(), snapshot() at any point
         self.metrics = MetricsRegistry()
         #: per-group (predicted, measured, rank-slot) records feeding
-        #: the run report; installed by train(trace=/report=)
+        #: the run report; installed by train(report=)
         self._recorder: Optional[RunRecorder] = None
-        #: the RunReport of the last traced/reported train() call
+        #: the RunReport of the last reported train() call
         self.last_report: Optional[RunReport] = None
         #: the loader train() last built/used — checkpointed so resume
         #: replays the exact remaining batch stream
@@ -236,6 +241,27 @@ class Engine:
         return self.strategy.plan(infos)
 
     # -- execute --------------------------------------------------------
+    def update_fn(self):
+        """The jitted optimizer update `(state, grads) -> state`, built
+        once per engine; it donates the state it is given."""
+        if self._apply_update is None:
+            import jax
+
+            from ..training.train_step import TrainState
+            opt = self.optimizer
+
+            # donating the state lets the new state reuse its buffers:
+            # without it the old and new params + fp32 moments are live
+            # at once, which does not fit one 16 GB chip at internvl3-2b
+            # widths
+            @partial(jax.jit, donate_argnums=0)
+            def apply_update(state, grads):
+                p, o = opt.update(grads, state.opt, state.params)
+                return TrainState(p, o)
+
+            self._apply_update = apply_update
+        return self._apply_update
+
     def execute(self, plan: ExecutionPlan, data: RaggedBatch, *,
                 update: bool = True,
                 measure: Optional[bool] = None) -> StepMetrics:
@@ -262,21 +288,7 @@ class Engine:
         loss, grads = self.executor.run_plan(self.state.params, plan,
                                              data, timings=timings)
         if update:
-            if self._apply_update is None:
-                from ..training.train_step import TrainState
-                opt = self.optimizer
-
-                # donating the state lets the new state reuse its
-                # buffers: without it the old and new params + fp32
-                # moments are live at once, which does not fit one
-                # 16 GB chip at internvl3-2b widths
-                @partial(jax.jit, donate_argnums=0)
-                def apply_update(state, grads):
-                    p, o = opt.update(grads, state.opt, state.params)
-                    return TrainState(p, o)
-
-                self._apply_update = apply_update
-            self.state = self._apply_update(self.state, grads)
+            self.state = self.update_fn()(self.state, grads)
         # the step ends when the device has the new state
         jax.block_until_ready(self.state if update else grads)
         step_time = time.perf_counter() - t0
@@ -376,13 +388,15 @@ class Engine:
 
         `trace`: a path (Chrome trace-event JSON is saved there), True,
         or a Tracer instance — records the run's timeline: scheduler
-        stages and the lookahead planner thread on host tracks, measured
-        group execution on one track per simulated rank (load the file
-        at https://ui.perfetto.dev). `report`: a path or True — builds
-        the post-run analytics RunReport (per-wave imbalance, per-rank
-        straggler scores, cost-model MAPE), kept on `self.last_report`
-        and saved as JSON when a path is given. Either option switches
-        execution to measuring mode (per-group synchronous timing), so
+        stages and the lookahead planner thread on host tracks, the
+        executor's dispatch and collect spans (load the file at
+        https://ui.perfetto.dev); groups keep their concurrent
+        dispatch. `report`: a path or True — builds the post-run
+        analytics RunReport (per-wave imbalance, per-rank straggler
+        scores, cost-model MAPE), kept on `self.last_report` and saved
+        as JSON when a path is given. `report` switches execution to
+        measuring mode (per-group synchronous timing; with a tracer,
+        measured group execution on one track per simulated rank), so
         the concurrent dispatch of disjoint groups is traded for
         observability — see docs/api.md "Observability"."""
         tracer: Optional[Tracer] = None
@@ -394,7 +408,7 @@ class Engine:
                 tracer = Tracer()
             else:
                 tracer = trace
-        observing = tracer is not None or bool(report)
+        observing = bool(report)
         if observing:
             self._recorder = RunRecorder(self.cluster.n_replicas)
         history: List[StepMetrics] = []

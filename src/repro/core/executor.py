@@ -69,6 +69,10 @@ from .scheduler import ExecutionPlan
 #: vlm/audio batches carry extra modal inputs the flattener doesn't pack.
 PACKABLE_FAMILIES = ("dense", "moe")
 
+#: XLA module name of the grad step (`fwd_bwd` in `_build_grad_fn`): the
+#: chip benchmark's device-trace readers find the program by this name
+GRAD_STEP_MODULE = "jit_fwd_bwd"
+
 
 def _token_nll(logits, labels):
     """Per-position next-token NLL (no masking applied)."""
@@ -76,6 +80,10 @@ def _token_nll(logits, labels):
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     return logz - gold
+
+
+def _tree_nbytes(tree) -> int:
+    return sum(a.nbytes for a in jax.tree.leaves(tree))
 
 
 def _masked_nll(logits, labels, mask):
@@ -340,17 +348,31 @@ class DHPExecutor:
                     handles.append((out, n_tok))
             t_collect = _time.perf_counter()
             for out, n_tok in handles:
-                loss, grads = out[0], out[1]
+                # wait: the host blocks on the group's device program
+                t0 = _time.perf_counter()
+                jax.block_until_ready(out)
+                loss = float(out[0])
+                t1 = _time.perf_counter()
+                # d2h: the gradient tree comes to host NumPy as it is
+                g_host = jax.device_get(out[1])
+                t2 = _time.perf_counter()
+                # sum: float32 cast (exact from bf16), token weight, add
+                w = n_tok
+                total_tokens += w
+                loss_acc += loss * w
                 if len(out) > 2:           # span-bearing: modality aux
                     a = np.asarray(out[2], np.float64)
                     aux_acc = a if aux_acc is None else aux_acc + a
-                w = n_tok
-                total_tokens += w
-                loss_acc += float(loss) * w
                 g_np = jax.tree.map(
-                    lambda a: np.asarray(a, np.float32) * w, grads)
+                    lambda a: a.astype(np.float32, copy=False) * w, g_host)
                 g_acc = g_np if g_acc is None else jax.tree.map(
                     np.add, g_acc, g_np)
+                if tr.enabled:
+                    t3 = _time.perf_counter()
+                    tr.complete("collect.wait", t0, t1 - t0, "exec")
+                    tr.complete("collect.d2h", t1, t2 - t1, "exec",
+                                args={"bytes": _tree_nbytes(g_host)})
+                    tr.complete("collect.sum", t2, t3 - t2, "exec")
             if tr.enabled:
                 # draining the handles forces the device sync for this
                 # micro-batch — the wave barrier
@@ -365,9 +387,15 @@ class DHPExecutor:
                 for k, name in enumerate(MODALITY_CLASSES)
                 if aux_acc[k, 1] > 0}
         self.last_run_stats = agg
+        t_up = _time.perf_counter()
         denom = max(total_tokens, 1.0)
         grads = jax.tree.map(lambda a: jnp.asarray(a / denom), g_acc)
         if tr.enabled:
+            # wait for the transfer itself (the update reads these
+            # buffers next anyway); untraced runs do not wait here
+            jax.block_until_ready(grads)
+            tr.complete("grad_upload", t_up, _time.perf_counter() - t_up,
+                        "exec", args={"bytes": _tree_nbytes(g_acc)})
             tr.complete("run_plan", t_run,
                         _time.perf_counter() - t_run, "exec",
                         args={"groups": agg["groups"],

@@ -68,7 +68,7 @@ class GroupRecord:
 class RunRecorder:
     """Collects GroupRecords across a run.
 
-    `Engine.train(trace=... / report=...)` installs one and feeds it
+    `Engine.train(report=...)` installs one and feeds it
     from `execute()`; tests can also append synthetic records directly
     via `add()`."""
 

@@ -24,6 +24,6 @@ def run_in_subprocess(script: str, n_devices: int = 8, timeout: int = 560):
     return r.stdout
 
 
-@pytest.fixture
+@pytest.fixture(scope="session")
 def subproc():
     return run_in_subprocess
