@@ -75,9 +75,9 @@ LOSS_RTOL = 5e-3
 #: and the in-kernel fp32 softmax adds less than that
 KERNEL_ATOL = 3e-2
 #: dhp vs static on one batch: the same tokens and parameters, grouped
-#: differently. Each group's gradient leaves the device in bf16 (2^-9
-#: relative) before the token-weighted fp32 sum, and different groupings
-#: round different partial sums
+#: differently. Each group's gradient comes out of its grad step in bf16
+#: (2^-9 relative) before the token-weighted fp32 sum, and different
+#: groupings round different partial sums
 RING_LOSS_RTOL = 2e-3
 RING_GNORM_RTOL = 1e-2
 
@@ -276,7 +276,9 @@ def ring_phase(cfg, devices) -> None:
         results[name] = (float(loss), gnorm)
         print(f"{name} loss={float(loss)!r} grad_norm={gnorm!r} "
               f"wall_s={time.perf_counter() - t0!r} "
-              f"exe_misses={engine.executor.last_run_stats['exe_misses']}",
+              f"exe_misses={engine.executor.last_run_stats['exe_misses']} "
+              f"grad_d2d_bytes="
+              f"{engine.executor.last_run_stats['grad_d2d_bytes']}",
               flush=True)
         del grads
     leaf = jax.tree.leaves(params)[0]

@@ -285,6 +285,11 @@ class Engine:
         self._prev_plan = plan
         timings: Optional[List[dict]] = [] if measure else None
         t0 = time.perf_counter()
+        # the state lives where the executor sums the gradients,
+        # committed there from the first step on (no copy), so the grad
+        # step and the update see one placement and compile once
+        self.state = jax.device_put(
+            self.state, self.executor.home(self.state.params))
         loss, grads = self.executor.run_plan(self.state.params, plan,
                                              data, timings=timings)
         if update:

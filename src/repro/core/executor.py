@@ -38,7 +38,12 @@ async dispatch executes them concurrently, which is exactly the paper's
 concurrent heterogeneous CP groups. Token-count-weighted gradient
 averaging across groups reproduces the static single-group gradient
 bit-for-bit in expectation (invariant tested in tests/test_parallel.py):
-dynamic regrouping changes WHERE sequences run, never the math.
+dynamic regrouping changes WHERE sequences run, never the math. The
+average is taken on the device, in float32 (`accumulate_grads`), on the
+devices that hold the parameters: a group on another sub-mesh sends its
+gradients there device to device, and outside measuring mode nothing
+waits for the device until the run's modality table is read after the
+last dispatch.
 
 This module targets the CPU multi-device demo (model_axis=1, params
 replicated). On a TPU pod the same code runs with model_axis=TP and
@@ -53,7 +58,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ModelConfig
 from ..data.pipeline import RaggedBatch, padded_batch
@@ -72,6 +77,36 @@ PACKABLE_FAMILIES = ("dense", "moe")
 #: XLA module name of the grad step (`fwd_bwd` in `_build_grad_fn`): the
 #: chip benchmark's device-trace readers find the program by this name
 GRAD_STEP_MODULE = "jit_fwd_bwd"
+#: XLA module name of the on-device gradient accumulation
+#: (`accumulate_grads`), found the same way
+GRAD_ACCUM_MODULE = "jit_accumulate_grads"
+
+
+@partial(jax.jit, donate_argnums=0)
+def accumulate_grads(acc, out, w, total=None):
+    """One group's share of a run's token-weighted mean, in float32 on
+    the device: `acc` + `out` cast to float32 (exact from bf16) and
+    times the group's token weight `w`, per leaf of (loss, grads), and
+    the modality aux table added unweighted. `acc` None starts the sums
+    (the first group); `total` divides the sums after the last group's
+    add. `out` is the grad step's (loss, grads[, aux]); the accumulator
+    is (loss, grads, aux or None), donated, so it is updated in place."""
+    loss, grads = out[0], out[1]
+    aux = out[2] if len(out) > 2 else None
+    f32 = lambda a: a.astype(jnp.float32) * w
+    if acc is None:
+        acc = (f32(loss), jax.tree.map(f32, grads), aux)
+    else:
+        acc_aux = acc[2]
+        if aux is not None:
+            acc_aux = aux if acc_aux is None else acc_aux + aux
+        acc = (acc[0] + f32(loss),
+               jax.tree.map(lambda a, g: a + f32(g), acc[1], grads),
+               acc_aux)
+    if total is None:
+        return acc
+    return (acc[0] / total, jax.tree.map(lambda a: a / total, acc[1]),
+            acc[2])
 
 
 def _token_nll(logits, labels):
@@ -242,6 +277,18 @@ class DHPExecutor:
         return np_batch, real, len(seqs) * bucket, bucket
 
     # ------------------------------------------------------------------
+    def home(self, params) -> NamedSharding:
+        """Where `run_plan` sums the gradients: replicated over the
+        devices that hold `params`, on a mesh named like the group
+        meshes (for parameters on one device, the degree-1 group mesh
+        there). Callers that commit their state here keep one placement,
+        and so one compiled program, from the first step on."""
+        devs = sorted(jax.tree.leaves(params)[0].devices(),
+                      key=lambda d: d.id)
+        return NamedSharding(Mesh(np.array(devs).reshape(-1, 1),
+                                  self.pool.axis_names), P())
+
+    # ------------------------------------------------------------------
     def run_plan(self, params, plan: ExecutionPlan, data: RaggedBatch,
                  *, timings: Optional[List[Dict[str, Any]]] = None
                  ) -> Tuple[jax.Array, Any]:
@@ -258,8 +305,10 @@ class DHPExecutor:
         it when measuring.
 
         `self.last_run_stats` always aggregates {real_tokens,
-        padded_tokens, padding_efficiency, exe_misses, groups} for the
-        run — the benchmark/CI telemetry feed. Span-bearing runs add
+        padded_tokens, padding_efficiency, exe_misses, groups,
+        grad_d2d_bytes} for the run — the benchmark/CI telemetry feed;
+        grad_d2d_bytes counts the group outputs copied device to device
+        onto the accumulator's devices. Span-bearing runs add
         "modality_loss": {class name: mean NLL} over every class that
         had at least one valid label (classes masked OUT of the
         training loss, e.g. bidirectional vision spans, still report)."""
@@ -267,16 +316,19 @@ class DHPExecutor:
         tr = get_tracer()
         t_run = _time.perf_counter()
         total_tokens = 0.0
-        g_acc = None
-        loss_acc = 0.0
-        aux_acc = None       # [n_classes, 2] (nll_sum, label_count)
+        acc = None           # (loss, grads, aux) sums, on the device
+        # the accumulator lives where the parameters the update reads do
+        home = self.home(params)
         agg: Dict[str, Any] = {"real_tokens": 0, "padded_tokens": 0,
-                               "exe_misses": 0, "groups": 0}
+                               "exe_misses": 0, "groups": 0,
+                               "grad_d2d_bytes": 0}
         # Rank slots come from the plan IR itself (including the
         # defensive wrap for oversubscribed micro-batches) so executor,
         # GroupDelta diffing and replay equality all agree on which rank
         # slice a group runs on.
         slots = iter(plan.group_slots(self.pool.n_replicas))
+        n_groups = sum(len(mb.groups) for mb in plan.micro_batches)
+        summed = 0
         self.last_exe_keys = []
         spans_by_id = (data.spans_by_id()
                        if hasattr(data, "spans_by_id") else {})
@@ -298,6 +350,10 @@ class DHPExecutor:
                         start, g.degree, len(seqs), bucket, with_spans)
                 self.last_exe_keys.append(key)
                 batch = {k: jnp.asarray(v) for k, v in np_batch.items()}
+                # the parameters go to the group's sub-mesh: device to
+                # device off the accumulator's devices, as they are on it
+                group_params = jax.device_put(params, NamedSharding(
+                    self.pool.mesh_for(start, g.degree), P()))
                 # weight groups by LOSS tokens when a loss mask exists —
                 # bidirectional-span labels carry no NLL, so counting
                 # them would dilute the span-bearing groups' gradients
@@ -309,7 +365,7 @@ class DHPExecutor:
                 agg["groups"] += 1
                 if timings is None:
                     t0 = _time.perf_counter()
-                    handles.append((step(params, batch), n_tok))  # async
+                    handles.append((step(group_params, batch), n_tok))  # async
                     if tr.enabled:
                         # host-side dispatch cost only: the device work
                         # runs asynchronously and is not observable
@@ -321,7 +377,7 @@ class DHPExecutor:
                                           "start_rank": start})
                 else:
                     t0 = _time.perf_counter()
-                    out = jax.block_until_ready(step(params, batch))
+                    out = jax.block_until_ready(step(group_params, batch))
                     dt = _time.perf_counter() - t0
                     timings.append({
                         "seq_ids": list(g.seq_ids),
@@ -348,57 +404,42 @@ class DHPExecutor:
                     handles.append((out, n_tok))
             t_collect = _time.perf_counter()
             for out, n_tok in handles:
-                # wait: the host blocks on the group's device program
+                # sum: dispatch the group's add into the accumulator;
+                # nothing here waits for the device
                 t0 = _time.perf_counter()
-                jax.block_until_ready(out)
-                loss = float(out[0])
-                t1 = _time.perf_counter()
-                # d2h: the gradient tree comes to host NumPy as it is
-                g_host = jax.device_get(out[1])
-                t2 = _time.perf_counter()
-                # sum: float32 cast (exact from bf16), token weight, add
-                w = n_tok
-                total_tokens += w
-                loss_acc += loss * w
-                if len(out) > 2:           # span-bearing: modality aux
-                    a = np.asarray(out[2], np.float64)
-                    aux_acc = a if aux_acc is None else aux_acc + a
-                g_np = jax.tree.map(
-                    lambda a: a.astype(np.float32, copy=False) * w, g_host)
-                g_acc = g_np if g_acc is None else jax.tree.map(
-                    np.add, g_acc, g_np)
+                if not all(x.sharding.is_equivalent_to(home, x.ndim)
+                           for x in jax.tree.leaves(out)):
+                    # the group ran off the accumulator's devices: its
+                    # outputs cross device to device, never via the host
+                    agg["grad_d2d_bytes"] += _tree_nbytes(out)
+                    out = jax.device_put(out, home)
+                total_tokens += n_tok
+                summed += 1
+                acc = accumulate_grads(
+                    acc, out, n_tok,
+                    max(total_tokens, 1.0) if summed == n_groups else None)
                 if tr.enabled:
-                    t3 = _time.perf_counter()
-                    tr.complete("collect.wait", t0, t1 - t0, "exec")
-                    tr.complete("collect.d2h", t1, t2 - t1, "exec",
-                                args={"bytes": _tree_nbytes(g_host)})
-                    tr.complete("collect.sum", t2, t3 - t2, "exec")
+                    tr.complete("collect.sum", t0,
+                                _time.perf_counter() - t0, "exec")
             if tr.enabled:
-                # draining the handles forces the device sync for this
-                # micro-batch — the wave barrier
                 tr.complete("collect", t_collect,
                             _time.perf_counter() - t_collect, "exec",
                             args={"groups": len(handles)})
         agg["padding_efficiency"] = (
             agg["real_tokens"] / max(agg["padded_tokens"], 1))
-        if aux_acc is not None:
+        loss, grads, aux = acc
+        if aux is not None:
+            # the one host read of the run, after the last dispatch
+            aux = np.asarray(aux, np.float64)
             agg["modality_loss"] = {
-                name: float(aux_acc[k, 0] / aux_acc[k, 1])
+                name: float(aux[k, 0] / aux[k, 1])
                 for k, name in enumerate(MODALITY_CLASSES)
-                if aux_acc[k, 1] > 0}
+                if aux[k, 1] > 0}
         self.last_run_stats = agg
-        t_up = _time.perf_counter()
-        denom = max(total_tokens, 1.0)
-        grads = jax.tree.map(lambda a: jnp.asarray(a / denom), g_acc)
         if tr.enabled:
-            # wait for the transfer itself (the update reads these
-            # buffers next anyway); untraced runs do not wait here
-            jax.block_until_ready(grads)
-            tr.complete("grad_upload", t_up, _time.perf_counter() - t_up,
-                        "exec", args={"bytes": _tree_nbytes(g_acc)})
             tr.complete("run_plan", t_run,
                         _time.perf_counter() - t_run, "exec",
                         args={"groups": agg["groups"],
                               "exe_misses": agg["exe_misses"],
                               "measured": timings is not None})
-        return jnp.asarray(loss_acc / denom), grads
+        return loss, grads

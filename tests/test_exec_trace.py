@@ -1,14 +1,15 @@
 """The executor's host-side spans: inside every `collect` span one
-`collect.wait`, `collect.d2h` and `collect.sum` per group, one
-`grad_upload` per `run_plan`, their `bytes` args, bit-identical results
-with tracing on and off, tracing without measuring mode, and the jit
-names the chip benchmark's device-trace readers match."""
+`collect.sum` per group (the dispatch of its on-device accumulate) and
+nothing else, no gradient transfer spans, the bytes that cross to the
+accumulator's devices, float32 results bit-identical with tracing on
+and off, tracing without measuring mode, and the jit names the chip
+benchmark's device-trace readers match."""
 import json
 
 import pytest
 
 from repro.api.engine import UPDATE_MODULE
-from repro.core.executor import GRAD_STEP_MODULE
+from repro.core.executor import GRAD_ACCUM_MODULE, GRAD_STEP_MODULE
 
 SCRIPT = r"""
 import json, re
@@ -16,6 +17,8 @@ import jax
 import numpy as np
 from repro.api import ClusterSpec, Engine, get_strategy
 from repro.configs import get_config
+from repro.core.executor import DHPExecutor, accumulate_grads
+from repro.core.scheduler import ExecutionPlan, GroupPlan, MicroBatchPlan
 from repro.data.pipeline import HeterogeneousLoader
 from repro.obs import Tracer, tracing
 from repro.obs.trace import PID_RANKS
@@ -42,6 +45,8 @@ def inside(c, e):
     return c["ts"] <= e["ts"] and e["ts"] + e["dur"] <= c["ts"] + c["dur"]
 
 children = ("collect.wait", "collect.d2h", "collect.sum")
+off_home = sum((start, degree) != (0, 1)
+               for *_, start, degree in plan.group_slots(4))
 collects = []
 for c in (e for e in spans if e["name"] == "collect"):
     kids = [e for e in spans if e["name"] in children and inside(c, e)]
@@ -53,33 +58,43 @@ for c in (e for e in spans if e["name"] == "collect"):
 leaves_on = jax.tree.leaves(g_on)
 leaves_off = jax.tree.leaves(g_off)
 
-# the module names of the grad step and the update, lowered on the CPU
+# the module names of the grad step, the update and the accumulate,
+# lowered on the CPU
 g = plan.micro_batches[0].groups[0]
 np_batch, _, _, bucket = ex._group_batch(
-    [data.by_id(i) for i in g.seq_ids], 1)
+    [data.by_id(i) for i in g.seq_ids], 1,
+    spans=[data.spans_by_id().get(i) for i in g.seq_ids])
 step, _, _ = ex._packed_grad_fn(0, 1, bucket, "modality_ids" in np_batch)
 batch = {k: jax.numpy.asarray(v) for k, v in np_batch.items()}
 
 def module(lowered):
     return re.search(r"module @(\S+)", lowered.as_text()).group(1)
 
+out = step(params, batch)
 modules = {"grad": module(step.lower(params, batch)),
-           "update": module(eng.update_fn().lower(eng.state, g_off))}
+           "update": module(eng.update_fn().lower(eng.state, g_off)),
+           "accumulate": module(accumulate_grads.lower(None, out, 1.0))}
+
+# one device: every group runs where the accumulator lives
+ex1 = DHPExecutor(cfg, devices=jax.devices()[:1])
+ids = [s.seq_id for s in data.infos]
+ex1.run_plan(params, ExecutionPlan(
+    [MicroBatchPlan([GroupPlan(ids[i:i + 4], 1, 0.0, 0)], 0.0, 1)
+     for i in range(0, len(ids), 4)], 0.0, 0.0, 0.0), data)
 
 res = {
     "groups": sum(len(mb.groups) for mb in plan.micro_batches),
     "collects": collects,
     "child_total": sum(e["name"] in children for e in spans),
     "n_run_plan": sum(e["name"] == "run_plan" for e in spans),
-    "n_upload": sum(e["name"] == "grad_upload" for e in spans),
-    "d2h_bytes": sorted({e["args"]["bytes"] for e in spans
-                         if e["name"] == "collect.d2h"}),
-    "upload_bytes": [e["args"]["bytes"] for e in spans
-                     if e["name"] == "grad_upload"],
+    "names": sorted({e["name"] for e in spans}),
+    "off_home": off_home,
+    "d2d_bytes": ex.last_run_stats["grad_d2d_bytes"],
+    "d2d_bytes_one_device": ex1.last_run_stats["grad_d2d_bytes"],
+    "out_bytes": sum(a.nbytes for a in jax.tree.leaves(out)),
     "grad_dtypes": sorted({str(a.dtype) for a in leaves_on}),
     "bf16_bytes": sum(np.asarray(a).nbytes
                       for a in jax.tree.leaves(params)),
-    "f32_bytes": sum(np.asarray(a).nbytes for a in leaves_on),
     "param_dtypes": sorted({str(a.dtype)
                             for a in jax.tree.leaves(params)}),
     "loss_same": np.asarray(loss_on).tobytes()
@@ -116,30 +131,38 @@ def traced(subproc):
     return info
 
 
-def test_collect_holds_one_wait_d2h_sum_per_group(traced):
+def test_collect_holds_one_sum_per_group(traced):
     assert traced["collects"]
     for c in traced["collects"]:
-        assert c["counts"] == {"collect.wait": c["groups"],
-                               "collect.d2h": c["groups"],
+        assert c["counts"] == {"collect.wait": 0, "collect.d2h": 0,
                                "collect.sum": c["groups"]}, c
         assert c["kids_dur"] <= c["dur"]
     # every child span lies inside a collect span
-    assert traced["child_total"] == 3 * traced["groups"]
+    assert traced["child_total"] == traced["groups"]
 
 
-def test_one_grad_upload_per_run_plan(traced):
+def test_no_gradient_transfer_spans(traced):
     assert traced["n_run_plan"] == 1
-    assert traced["n_upload"] == 1
+    for name in ("grad_upload", "collect.wait", "collect.d2h"):
+        assert name not in traced["names"]
 
 
-def test_span_bytes_are_the_trees_bytes(traced):
+def test_d2d_bytes_are_the_moved_outputs(traced):
+    # a group off device 0, where the parameters live, sends its whole
+    # output over: loss, bf16 gradients and, as every group of this
+    # batch carries video spans, the modality table
+    assert traced["off_home"] > 0
+    assert traced["out_bytes"] > traced["bf16_bytes"]
+    assert traced["d2d_bytes"] == traced["off_home"] * traced["out_bytes"]
+
+
+def test_d2d_bytes_zero_on_one_device(traced):
+    assert traced["d2d_bytes_one_device"] == 0
+
+
+def test_gradients_stay_float32(traced):
     assert traced["param_dtypes"] == ["bfloat16"]
     assert traced["grad_dtypes"] == ["float32"]
-    # the bf16 gradient tree (the parameters' shapes and dtypes) comes
-    # down; the float32 average goes up
-    assert traced["d2h_bytes"] == [traced["bf16_bytes"]]
-    assert traced["upload_bytes"] == [traced["f32_bytes"]]
-    assert traced["f32_bytes"] == 2 * traced["bf16_bytes"]
 
 
 def test_results_bit_identical_with_and_without_tracer(traced):
@@ -149,7 +172,7 @@ def test_results_bit_identical_with_and_without_tracer(traced):
 
 def test_tracing_alone_does_not_switch_measuring_mode(traced):
     assert "dispatch" in traced["train_names"]
-    assert "collect.d2h" in traced["train_names"]
+    assert "collect.sum" in traced["train_names"]
     assert "execute" not in traced["train_names"]
     assert traced["train_rank_spans"] == 0
     assert traced["last_report_none"]
@@ -157,7 +180,8 @@ def test_tracing_alone_does_not_switch_measuring_mode(traced):
 
 @pytest.mark.parametrize("program,name,constant", [
     ("grad", "jit_fwd_bwd", GRAD_STEP_MODULE),
-    ("update", "jit_apply_update", UPDATE_MODULE)])
+    ("update", "jit_apply_update", UPDATE_MODULE),
+    ("accumulate", "jit_accumulate_grads", GRAD_ACCUM_MODULE)])
 def test_jit_names_the_benchmark_reads(traced, program, name, constant):
     assert constant == name
     assert traced["modules"][program] == name

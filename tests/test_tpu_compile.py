@@ -18,8 +18,9 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
-from repro.core.executor import DHPExecutor
+from repro.core.executor import DHPExecutor, accumulate_grads
 from repro.core.group_pool import GroupPool
+from repro.core.packing import MODALITY_CLASSES
 from repro.kernels.flash_attention import (flash_attention_flat,
                                            flash_attention_packed_flat)
 from repro.models.model import init_params
@@ -109,3 +110,26 @@ def test_executor_grad_step_fits_one_chip(topo):
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert live < HBM_BYTES, live
+
+
+@pytest.mark.parametrize("last", [False, True], ids=["add", "add+divide"])
+def test_accumulate_grads_updates_in_place(one_chip, last):
+    """The executor's float32 gradient accumulator at the benchmark's
+    internvl3-2b widths (4 layers): the donated accumulator's buffers
+    become the result's, so a group's add needs no second copy."""
+    cfg = get_config("internvl3-2b").with_(family="dense", vlm=None,
+                                           n_layers=4)
+    tree = jax.eval_shape(lambda k: init_params(k, cfg),
+                          jax.random.PRNGKey(0))
+    aux = _shape((len(MODALITY_CLASSES), 2), jnp.float32, one_chip)
+    scalar = _shape((), jnp.float32, one_chip)
+    acc = (scalar, jax.tree.map(
+        lambda a: _shape(a.shape, jnp.float32, one_chip), tree), aux)
+    out = (scalar, jax.tree.map(
+        lambda a: _shape(a.shape, a.dtype, one_chip), tree), aux)
+    total = scalar if last else None
+    mem = accumulate_grads.lower(acc, out, scalar, total).compile(
+        ).memory_analysis()
+    f32_bytes = sum(4 * a.size for a in jax.tree.leaves(tree))
+    assert mem.alias_size_in_bytes >= f32_bytes, mem
+    assert mem.temp_size_in_bytes < f32_bytes // 100, mem
