@@ -40,13 +40,15 @@ def readings(spec, seed, device):
              for k in range(harness.CHECKED_STEPS)]
     half = [(s[::2], t[::2]) for s, t in steps]
     t0 = time.perf_counter()
-    ref = reference.run(cfg, mix, key, steps, device=device)
+    model = spec["model"]
+    ref = reference.run(model, cfg, mix, key, steps, device=device)
     out = {"reference_s": time.perf_counter() - t0}
     zeros = [0.0] * len(ref["leaves"])
     runs = {
-        "control": reference.run(cfg, mix, key, steps, precision="fp8",
-                                 device=device),
-        "half_batch": reference.run(cfg, mix, key, half, device=device),
+        "control": reference.run(model, cfg, mix, key, steps,
+                                 precision="fp8", device=device),
+        "half_batch": reference.run(model, cfg, mix, key, half,
+                                    device=device),
         "state_unchanged": {**ref, "grad_norms": zeros,
                             "change_norms": zeros},
     }

@@ -1,14 +1,14 @@
 """Model FLOPs of a training step, from the configuration and the traffic.
 
-Counted per token: 6 x (the matmul parameters of every layer + the LM
-head): forward 2, backward 4. The embedding lookup is free. Counted per
-attended (query, key) pair and layer: 12 x heads x head_dim (QK^T and
-PV, 2 x 2 x H x Dh forward, three times that with the backward).
-Attended pairs are those the mask allows inside one sequence: causal,
-plus the forward pairs of each bidirectional block. Pairs across packed
-sequences and padding are never counted, nor is recomputation, so the
-count is the same however the planner groups the sequences, and no
-kernel that skips masked tiles can push a share of the peak above 1.
+The model module (`models/<model>.py`) counts per token
+(`token_flops(cfg)`: the matmuls a token passes through, forward and
+backward) and per attended (query, key) pair (`pair_flops(cfg)`, over
+all layers). Attended pairs are those the mask allows inside one
+sequence: causal, plus the forward pairs of each bidirectional block.
+Pairs across packed sequences and padding are never counted, nor is
+recomputation, so the count is the same however the planner groups the
+sequences, and no kernel that skips masked tiles can push a share of
+the peak above 1.
 """
 from __future__ import annotations
 
@@ -17,24 +17,8 @@ from typing import Dict, Iterable
 import traffic
 
 
-def layer_matmul_params(cfg: Dict) -> int:
-    d, dh = cfg["d_model"], cfg["head_dim"]
-    attn = d * dh * (2 * cfg["n_heads"] + 2 * cfg["kv_heads"])
-    n_mlp = 3 if cfg["activation"] == "swiglu" else 2
-    return attn + n_mlp * d * cfg["d_ff"]
-
-
-def token_flops(cfg: Dict) -> int:
-    return 6 * (cfg["n_layers"] * layer_matmul_params(cfg)
-                + cfg["d_model"] * cfg["vocab"])
-
-
-def pair_flops(cfg: Dict) -> int:
-    return 12 * cfg["n_heads"] * cfg["head_dim"] * cfg["n_layers"]
-
-
-def step_flops(cfg: Dict, seqs: Iterable[traffic.Seq]) -> int:
+def step_flops(model, cfg: Dict, seqs: Iterable[traffic.Seq]) -> int:
     seqs = list(seqs)
     tokens = sum(traffic.seq_len(s) for s in seqs)
     pairs = sum(traffic.pairs(s) for s in seqs)
-    return tokens * token_flops(cfg) + pairs * pair_flops(cfg)
+    return tokens * model.token_flops(cfg) + pairs * model.pair_flops(cfg)

@@ -7,10 +7,16 @@ Everything that belongs to one configuration, traffic mix or metric is
 a file of its own, found by name:
 
     BENCHMARK.json          cells, metrics, configuration files
-    configs/<config>.json   sizes as run, the published ones, the cut
+    configs/<config>.json   sizes as run, the published ones, the cut,
+                            and `"model"`: the model module it names
+    models/<model>.py       the model: `program_config`, `shapes`,
+                            `row_loss`, `token_flops`, `pair_flops`
+                            (one may build on another: `from models
+                            import dense_gqa`)
     traffic/<mix>.json      dataset profile, step budget, planner knobs
     limits/<cell>.json      the limit of each number `correct` compares
     metrics/<metric>.py     `read(run) -> float | None`, one per metric
+    roofline.py             `share`: a kernel's share of its roofline
     peaks.json              peak FLOP/s and bytes/s per `device_kind`
 
 A run, in one process:
@@ -26,12 +32,23 @@ A run, in one process:
    program's own tracer. The check follows steps 0-2: their losses, and
    each leaf's change after step 2.
 3. after the window: the device's peak memory is read, the program's
-   state is freed, and the plain float32 reference (`reference.py`)
-   trains the same weights through the same steps 0-2.
+   state is freed, and the plain float32 reference (`reference.py`,
+   with the model module's `row_loss`) trains the same weights through
+   the same steps 0-2.
 4. the last line of standard output: `correct`, `attempted`, `failed`,
    `metrics`, `device`, with `--trace 1` `breakdown`, and `checks`
    (each compared number beside its limit), which are also the last
    lines on standard error.
+
+What a metric reader is handed (`run`): `cfg`, the configuration;
+`seqs`, each window step's sequences (`traffic.Seq`); `steps`, one row a
+window step with the step's `StepMetrics` and every int or float entry
+of the executor's `last_run_stats`; `spans` and `counters`, the
+program's tracer events (`ph: "X"` and `ph: "C"`) on the host clock;
+`device`, the reduced profiler trace (`trace_reduce.reduce`: busy time,
+`programs`, every op's self time in `ops`); `peak`, the chip's row of
+`peaks.json`; and the window's time, tokens and model FLOPs. `spans`
+and `counters` are empty and `device` is None unless `--trace 1`.
 
 Without an accelerator, or with fewer chips than the cell asks for, it
 exits with code 2 and prints no result.
@@ -48,7 +65,8 @@ import shutil
 import sys
 import tempfile
 import time
-from functools import partial
+from functools import lru_cache, partial
+from numbers import Real
 from pathlib import Path
 from typing import Dict, List, Optional
 
@@ -65,10 +83,6 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent.parent
 #: steps the reference follows: the set-up step and the window's first two
 CHECKED_STEPS = 3
-#: keys of a configuration file that size the program's model
-PROGRAM_KEYS = model_init.SIZE_KEYS + (
-    "rope_theta", "norm_eps", "param_dtype", "remat", "activation",
-    "tie_embeddings")
 
 
 class NoChip(RuntimeError):
@@ -90,6 +104,7 @@ def load_cell(name: str, root: Path = ROOT, here: Path = HERE) -> Dict:
     return {
         "cell": cell,
         "cfg": cfg,
+        "model": model_of(cfg, here),
         "mix": traffic.load(here / "traffic" / f"{cell['traffic']}.json"),
         "limits": json.loads(
             (here / "limits" / f"{name}.json").read_text())["limits"],
@@ -101,13 +116,31 @@ def load_cell(name: str, root: Path = ROOT, here: Path = HERE) -> Dict:
     }
 
 
-def reader(here: Path, metric: str):
-    path = here / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
+def _module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(here: Path, metric: str):
+    return _module(here / "metrics" / f"{metric}.py",
+                   f"bench_metric_{metric.replace('.', '_')}").read
+
+
+@lru_cache(maxsize=None)
+def _model(path: Path):
+    return _module(path, f"bench_model_{path.stem}")
+
+
+def model_of(cfg: Dict, here: Path = HERE):
+    """The model module the configuration names (`"model"`), loaded
+    once from `models/<model>.py`."""
+    known = sorted(p.stem for p in (here / "models").glob("*.py"))
+    if cfg.get("model") not in known:
+        raise SystemExit(f"configuration {cfg.get('name')!r} names model "
+                         f"{cfg.get('model')!r}; known: {known}")
+    return _model(here / "models" / f"{cfg['model']}.py")
 
 
 def peak_of(kind: str, here: Path = HERE) -> Dict:
@@ -121,12 +154,6 @@ def peak_of(kind: str, here: Path = HERE) -> Dict:
 # --------------------------------------------------------------------------
 # the program's side
 # --------------------------------------------------------------------------
-def program_config(cfg: Dict):
-    from repro.configs import get_config
-    return get_config(cfg["arch"]).with_(
-        family="dense", vlm=None, **{k: cfg[k] for k in PROGRAM_KEYS})
-
-
 def ragged(seqs, toks, modality: str):
     """A step of the generator in the program's feed types."""
     from repro.core.cost_model import MMSequence, ModalitySpan
@@ -146,6 +173,23 @@ def annotate(name: str, fn):
         with jax.profiler.TraceAnnotation(name):
             return fn(*a, **kw)
     return wrapped
+
+
+def program_events(events: List[Dict], t_anchor: float):
+    """The program tracer's spans (`ph: "X"`: name, `start_s`, `dur_s`)
+    and counter samples (`ph: "C"`: name, `start_s`, `values`), on the
+    host clock: the tracer's `trace_reduce.ANCHOR` event was recorded at
+    `t_anchor`."""
+    t0 = t_anchor - next(e["ts"] for e in events
+                         if e["name"] == trace_reduce.ANCHOR) * 1e-6
+    spans = [{"name": e["name"], "start_s": t0 + e["ts"] * 1e-6,
+              "dur_s": e["dur"] * 1e-6}
+             for e in events if e.get("ph") == "X"
+             and e["name"] != trace_reduce.ANCHOR]
+    counters = [{"name": e["name"], "start_s": t0 + e["ts"] * 1e-6,
+                 "values": e["args"]}
+                for e in events if e.get("ph") == "C"]
+    return spans, counters
 
 
 class Feed:
@@ -176,10 +220,11 @@ class Feed:
 
 class Probe:
     """The `log` callback of `Engine.train`: what the program's state
-    and counters say after each step. After step 0 it reads the first
-    gradient (from the optimizer's first moment); after the last checked
-    step, if the feed has fetched another, each leaf's change, on the
-    device and without waiting for it."""
+    and counters say after each step (every number in the executor's
+    `last_run_stats`, and the planner's wait). After step 0 it reads the
+    first gradient (from the optimizer's first moment); after the last
+    checked step, if the feed has fetched another, each leaf's change,
+    on the device and without waiting for it."""
 
     def __init__(self, engine, b1: float, change):
         self.engine, self.b1, self.change = engine, b1, change
@@ -192,9 +237,9 @@ class Probe:
     def __call__(self, _summary: str) -> None:
         eng = self.engine
         stats = eng.executor.last_run_stats
-        self.rows.append({"wait_ms": eng.strategy.last_wait_ms,
-                          "real_tokens": stats["real_tokens"],
-                          "padded_tokens": stats["padded_tokens"]})
+        self.rows.append({**{k: v for k, v in stats.items()
+                             if isinstance(v, Real)},
+                          "wait_ms": eng.strategy.last_wait_ms})
         self.done += 1
         if self.done == 1:
             # first moment after one Adam step is (1 - b1) * gradient
@@ -216,7 +261,8 @@ def run_cell(spec: Dict, seed: int, seconds: float, trace: bool,
     from repro.training.optimizer import AdamW
     from repro.training.train_step import TrainState
 
-    cell, cfg, mix = spec["cell"], spec["cfg"], spec["mix"]
+    cell, cfg, mix, model = (spec[k] for k in ("cell", "cfg", "mix",
+                                               "model"))
     chips = cell["chips"]
     used = devices[:chips]
     key = traffic.weight_key(seed)
@@ -230,15 +276,17 @@ def run_cell(spec: Dict, seed: int, seconds: float, trace: bool,
         devices=used, mem_budget=float(mix["rank_budget_tokens"]),
         bucketing=partial(multiple_bucket,
                           multiple=mix["bucket_multiple"]))
-    engine = Engine(program_config(cfg), cluster, strategy=mix["strategy"],
+    engine = Engine(model.program_config(cfg), cluster,
+                    strategy=mix["strategy"],
                     optimizer=AdamW(**mix["optimizer"]), seed=0)
-    params = model_init.make_params(cfg, key)
+    params = model_init.make_params(model, cfg, key)
     engine.state = TrainState(params=params,
                               opt=engine.optimizer.init(params))
     del params
 
     def change(params):
-        return checks.diff_norms(params, model_init.make_params(cfg, key))
+        return checks.diff_norms(params,
+                                 model_init.make_params(model, cfg, key))
 
     # -- set-up: step 0, which compiles, through the window's call --------
     probe = Probe(engine, mix["optimizer"]["b1"], change)
@@ -293,7 +341,7 @@ def run_cell(spec: Dict, seed: int, seconds: float, trace: bool,
     probe.engine = None
     del engine, setup
     gc.collect()
-    ref = reference.run(cfg, mix, key,
+    ref = reference.run(model, cfg, mix, key,
                         [traffic.step(mix, cfg["vocab"], seed, k)
                          for k in range(CHECKED_STEPS)],
                         device=used[0])
@@ -307,18 +355,19 @@ def run_cell(spec: Dict, seed: int, seconds: float, trace: bool,
     correct = checks.judge(numbers, spec["limits"]) and failed == 0
 
     run = {
-        "cell": cell["name"], "chips": chips, "peak": peak,
+        "cell": cell["name"], "chips": chips, "peak": peak, "cfg": cfg,
         "setup_s": setup_s, "window_s": window_s,
         "tokens": mix["step_tokens"] * len(window),
-        "model_flops": float(sum(flops.step_flops(cfg, s)
+        "seqs": feed.steps,
+        "model_flops": float(sum(flops.step_flops(model, cfg, s)
                                  for s in feed.steps)),
         "memory_peak_bytes": mem_peak,
-        "steps": [{"schedule_ms": m.schedule_ms,
+        "steps": [{**row, "schedule_ms": m.schedule_ms,
                    "padding_eff": m.padding_efficiency,
                    "exe_misses": m.exe_misses,
-                   "step_time_s": m.step_time_s, **row}
+                   "step_time_s": m.step_time_s}
                   for m, row in zip(window, probe.rows)],
-        "spans": [], "device": None,
+        "spans": [], "counters": [], "device": None,
     }
     dev = used[0]
     result = {"correct": correct, "attempted": len(window),
@@ -328,15 +377,8 @@ def run_cell(spec: Dict, seed: int, seconds: float, trace: bool,
                          "count": len(devices),
                          "memory_peak_bytes": mem_peak}}
     if trace:
-        events = tracer.to_json()["traceEvents"]
-        t0_tracer = t_anchor - next(
-            e["ts"] for e in events
-            if e["name"] == trace_reduce.ANCHOR) * 1e-6
-        run["spans"] = [
-            {"name": e["name"], "start_s": t0_tracer + e["ts"] * 1e-6,
-             "dur_s": e["dur"] * 1e-6}
-            for e in events if e.get("ph") == "X"
-            and e["name"] != trace_reduce.ANCHOR]
+        run["spans"], run["counters"] = program_events(
+            tracer.to_json()["traceEvents"], t_anchor)
         host_spans += [(f"program.{s['name']}", s["start_s"],
                         s["start_s"] + s["dur_s"]) for s in run["spans"]]
         xplane = next(Path(trace_dir).rglob("*.xplane.pb"))

@@ -3,56 +3,46 @@
 The benchmark makes the weights itself, on the device, in one jitted
 call, in the dtype the configuration states: the program is handed
 them, and the reference makes the same ones again from the same seed.
-The layout is the program's interface (a dict of stacked layers):
-
-    embed [V, D]   head [D, V]   ln_f.scale [D]
-    layers: ln1.scale, ln2.scale [L, D]
-            attn.wq [L, D, H*Dh]  attn.wk, attn.wv [L, D, Hkv*Dh]
-            attn.wo [L, H*Dh, D]
-            mlp.up, mlp.gate [L, D, F]   mlp.down [L, F, D]
+The layout is the model module's `shapes(cfg)` (`models/<model>.py`):
+a tree of dicts whose leaves are shapes, one key of the seed each, in
+the tree's flattened order.
 
 Matrices are normal with standard deviation 1/sqrt(fan_in), the
-embedding 0.02, norm scales one.
+embedding 0.02, norm scales one. A model module may set the rule of a
+leaf of its own in `INIT`, a dict from the leaf's name (`leaf_names`)
+to `rule(key, shape, dtype)`.
 """
 from __future__ import annotations
 
 import functools
+import json
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
-SIZE_KEYS = ("n_layers", "d_model", "n_heads", "kv_heads", "head_dim",
-             "d_ff", "vocab")
-
-
-def shapes(cfg: Dict) -> Dict:
-    L, D, F, V = (cfg[k] for k in ("n_layers", "d_model", "d_ff", "vocab"))
-    q = cfg["n_heads"] * cfg["head_dim"]
-    kv = cfg["kv_heads"] * cfg["head_dim"]
-    return {
-        "embed": (V, D), "head": (D, V), "ln_f": {"scale": (D,)},
-        "layers": {
-            "ln1": {"scale": (L, D)}, "ln2": {"scale": (L, D)},
-            "attn": {"wq": (L, D, q), "wk": (L, D, kv), "wv": (L, D, kv),
-                     "wo": (L, q, D)},
-            "mlp": {"up": (L, D, F), "gate": (L, D, F), "down": (L, F, D)},
-        },
-    }
 
 
 def _is_shape(x) -> bool:
     return isinstance(x, tuple)
 
 
+def _name(path) -> str:
+    return "/".join(str(p.key) for p in path)
+
+
 @functools.lru_cache(maxsize=8)
-def _initializer(sizes: tuple, dtype_name: str):
+def _initializer(model, cfg_json: str):
+    cfg = json.loads(cfg_json)
     paths, treedef = jax.tree_util.tree_flatten_with_path(
-        shapes(dict(sizes)), is_leaf=_is_shape)
-    dtype = DTYPES[dtype_name]
+        model.shapes(cfg), is_leaf=_is_shape)
+    dtype = DTYPES[cfg["param_dtype"]]
+    rules = getattr(model, "INIT", {})
 
     def leaf(key, path, shape):
+        if _name(path) in rules:
+            return rules[_name(path)](key, shape, dtype)
         name = path[-1].key
         if name == "scale":
             return jnp.ones(shape, dtype)
@@ -68,14 +58,13 @@ def _initializer(sizes: tuple, dtype_name: str):
     return jax.jit(init)
 
 
-def make_params(cfg: Dict, raw_key, device=None):
+def make_params(model, cfg: Dict, raw_key, device=None):
     """The configuration's weights from `raw_key` (uint32[2]), in
     `cfg["param_dtype"]`, made on `device` in one jitted call."""
     key = jax.device_put(jnp.asarray(raw_key, jnp.uint32), device)
-    sizes = tuple((k, cfg[k]) for k in SIZE_KEYS)
-    return _initializer(sizes, cfg["param_dtype"])(key)
+    return _initializer(model, json.dumps(cfg, sort_keys=True))(key)
 
 
 def leaf_names(tree) -> list:
-    return ["/".join(str(p.key) for p in path)
+    return [_name(path)
             for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]

@@ -1,23 +1,20 @@
 """Plain float32 reference of the training steps the benchmark checks.
 
-A dense GQA decoder written from the configuration alone (it imports
-nothing of the program): RMSNorm, rotary q/k, grouped-query attention
-under the mixed mask (causal, plus bidirectional blocks, never across
-sequences), SwiGLU MLP, untied LM head, next-token NLL over the labels
-whose token is not inside a bidirectional block, mean over the step's
-labelled tokens. AdamW with decoupled weight decay on the matrices.
-Every matmul runs at `Precision.HIGHEST`; parameters are stored between
-steps in the configuration's dtype (bf16 parameters are rounded after
-each update, as a bf16-parameter trainer must), all arithmetic is
-float32.
+The model is the configuration's model module (`models/<model>.py`):
+its `row_loss(p, row, cfg, mm)` is the forward pass and summed
+next-token NLL of one packed row, written from the configuration alone
+(it imports nothing of the program). Here, once for every model: the
+step's loss is the mean over its labelled tokens; AdamW with decoupled
+weight decay on every leaf but the norm scales. Every matmul runs at
+`Precision.HIGHEST`; parameters are stored between steps in the
+configuration's dtype (bf16 parameters are rounded after each update,
+as a bf16-parameter trainer must), all arithmetic is float32.
 
 It runs in blocks: a step's sequences are packed first-fit into rows
 of `row_tokens`, one row's gradient at a time is added into a float32
-accumulator, attention is computed per block of queries and the loss
-per block of tokens, each block recomputed in the backward pass. The
-optimizer's moments wait on the host between steps, so that the
-parameters, the accumulator and one row's gradient fit beside the
-activations on one chip.
+accumulator. The optimizer's moments wait on the host between steps,
+so that the parameters, the accumulator and one row's gradient fit
+beside the activations on one chip.
 
 `precision="fp8"` is the control: the same steps with every matmul
 operand rounded to 8-bit floats (4 exponent and 3 mantissa bits, scaled
@@ -28,7 +25,7 @@ bf16 that would tempt a later change.
 from __future__ import annotations
 
 import functools
-import math
+import json
 from typing import Dict, List, Sequence
 
 import jax
@@ -40,8 +37,6 @@ import model_init
 import traffic
 
 HIGHEST = jax.lax.Precision.HIGHEST
-QUERY_BLOCK = 1024
-LOSS_BLOCK = 512
 
 
 # --------------------------------------------------------------------------
@@ -97,6 +92,12 @@ def _matmul(precision: str):
 # --------------------------------------------------------------------------
 def pack_rows(seqs: Sequence[traffic.Seq], toks: Sequence[np.ndarray],
               row_tokens: int) -> List[Dict[str, np.ndarray]]:
+    """The rows a model's `row_loss` reads, each of `row_tokens`:
+    `tokens`; `labels`, the next token; `lmask`, 1 where the label
+    counts (its token is not inside a bidirectional block); `pos`, the
+    position within the sequence; `seg`, the sequence's index in the row
+    (-1: padding); `block`, the bidirectional block's index in the row
+    (-1: causal)."""
     bins: List[List[int]] = []
     used: List[int] = []
     for i, s in enumerate(seqs):
@@ -142,112 +143,16 @@ def pack_rows(seqs: Sequence[traffic.Seq], toks: Sequence[np.ndarray],
     return rows
 
 
-# --------------------------------------------------------------------------
-# the model
-# --------------------------------------------------------------------------
-def _block(size: int, n: int) -> int:
-    blk = min(size, n)
-    if n % blk:
-        raise ValueError(f"row of {n} tokens is not a multiple of {blk}")
-    return blk
-
-
-def _rms(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
-        * scale
-
-
-def _rope(x, pos, theta):
-    """Rotary embedding on pairs of dims (2i, 2i+1), the program's
-    layout. x [S, heads, Dh], pos [S]."""
-    dh = x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
-    ang = pos[:, None].astype(jnp.float32) * inv
-    sin, cos = jnp.sin(ang)[:, None], jnp.cos(ang)[:, None]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                     -1).reshape(x.shape)
-
-
-def _attention(q, k, v, row):
-    """q [S, H, Dh], k/v [S, Hkv, Dh]; per block of queries."""
-    S, H, dh = q.shape
-    hkv = k.shape[1]
-    qg = q.reshape(S, hkv, H // hkv, dh) / math.sqrt(dh)
-    blk = _block(QUERY_BLOCK, S)
-    seg, block = row["seg"], row["block"]
-    idx = jnp.arange(S)
-
-    @jax.checkpoint
-    def one(i):
-        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * blk, blk)
-        qs, qi, sq, bq = sl(qg), sl(idx), sl(seg), sl(block)
-        s = jnp.einsum("skgd,tkd->skgt", qs, k, precision=HIGHEST)
-        allowed = ((qi[:, None] >= idx[None, :])
-                   | ((bq[:, None] >= 0) & (bq[:, None] == block[None])))
-        allowed &= (sq[:, None] == seg[None]) & (sq[:, None] >= 0)
-        p = jax.nn.softmax(jnp.where(allowed[:, None, None, :], s, -1e30))
-        # padding rows attend nothing: zero, not a uniform average
-        p = p * jnp.any(allowed, -1)[:, None, None, None]
-        return jnp.einsum("skgt,tkd->skgd", p, v, precision=HIGHEST)
-
-    out = jax.lax.map(one, jnp.arange(S // blk))
-    return out.reshape(S, H * dh)
-
-
-def _row_nll(p, row, cfg, mm):
-    """Sum of the row's labelled next-token NLL, and the label count."""
-    eps, theta = cfg["norm_eps"], cfg["rope_theta"]
-    H, hkv, dh = cfg["n_heads"], cfg["kv_heads"], cfg["head_dim"]
-    S = row["tokens"].shape[0]
-    x = p["embed"][row["tokens"]]
-
-    @jax.checkpoint
-    def layer(x, w):
-        h = _rms(x, w["ln1"]["scale"], eps)
-        q = _rope(mm(h, w["attn"]["wq"]).reshape(S, H, dh), row["pos"],
-                  theta)
-        k = _rope(mm(h, w["attn"]["wk"]).reshape(S, hkv, dh), row["pos"],
-                  theta)
-        v = mm(h, w["attn"]["wv"]).reshape(S, hkv, dh)
-        x = x + mm(_attention(q, k, v, row), w["attn"]["wo"])
-        h = _rms(x, w["ln2"]["scale"], eps)
-        a = mm(h, w["mlp"]["gate"])
-        x = x + mm(a * jax.nn.sigmoid(a) * mm(h, w["mlp"]["up"]),
-                   w["mlp"]["down"])
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, p["layers"])
-    h = _rms(x, p["ln_f"]["scale"], eps)
-    blk = _block(LOSS_BLOCK, S)
-
-    @jax.checkpoint
-    def loss_block(i):
-        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * blk, blk)
-        logits = mm(sl(h), p["head"])
-        gold = jnp.take_along_axis(logits, sl(row["labels"])[:, None],
-                                   -1)[:, 0]
-        nll = jax.nn.logsumexp(logits, -1) - gold
-        return jnp.sum(nll * sl(row["lmask"]))
-
-    total = jnp.sum(jax.lax.map(loss_block, jnp.arange(S // blk)))
-    return total, jnp.sum(row["lmask"])
-
-
 @functools.lru_cache(maxsize=4)
-def _programs(cfg_items: tuple, precision: str, opt_items: tuple):
-    cfg, opt = dict(cfg_items), dict(opt_items)
+def _programs(model, cfg_json: str, precision: str, opt_items: tuple):
+    cfg, opt = json.loads(cfg_json), dict(opt_items)
     mm = _matmul(precision)
     storage = model_init.DTYPES[cfg["param_dtype"]]
 
-    def row_loss(p, row):
-        total, _ = _row_nll(p, row, cfg, mm)
-        return total
-
     @jax.jit
     def row_grad(p, row):
-        total, g = jax.value_and_grad(row_loss)(p, row)
-        return total, g
+        return jax.value_and_grad(
+            lambda p: model.row_loss(p, row, cfg, mm))(p)
 
     @functools.partial(jax.jit, donate_argnums=0)
     def add(acc, g):
@@ -273,24 +178,18 @@ def _programs(cfg_items: tuple, precision: str, opt_items: tuple):
     return row_grad, add, scale, adam_leaf
 
 
-MODEL_KEYS = model_init.SIZE_KEYS + ("norm_eps", "rope_theta",
-                                     "param_dtype", "activation")
-
-
-def run(cfg: Dict, mix: Dict, raw_key, steps, *, precision="fp32",
+def run(model, cfg: Dict, mix: Dict, raw_key, steps, *, precision="fp32",
         device=None) -> Dict[str, list]:
     """Train the configuration's weights from `raw_key` through `steps`
-    (a list of (seqs, toks) per step) and read what the benchmark
-    compares: each step's loss, the first step's gradient norm per leaf,
-    and each leaf's change over all the steps."""
-    if cfg["activation"] != "swiglu" or cfg.get("tie_embeddings"):
-        raise ValueError("the reference models untied SwiGLU decoders")
+    (a list of (seqs, toks) per step) with the model module `model`, and
+    read what the benchmark compares: each step's loss, the first step's
+    gradient norm per leaf, and each leaf's change over all the steps."""
     row_grad, add, scale, adam_leaf = _programs(
-        tuple((k, cfg[k]) for k in MODEL_KEYS), precision,
+        model, json.dumps(cfg, sort_keys=True), precision,
         tuple(sorted(mix["optimizer"].items())))
     device = device or jax.devices()[0]
     p = jax.tree.map(lambda a: a.astype(jnp.float32),
-                     model_init.make_params(cfg, raw_key, device))
+                     model_init.make_params(model, cfg, raw_key, device))
     names = model_init.leaf_names(p)
     treedef = jax.tree.structure(p)
     moments = None                       # (m, v) per leaf, on the host
@@ -328,6 +227,6 @@ def run(cfg: Dict, mix: Dict, raw_key, steps, *, precision="fp32",
             del gi, mi, vi
         moments = new_m
         p = jax.tree.unflatten(treedef, new_p)
-    p0 = model_init.make_params(cfg, raw_key, device)
+    p0 = model_init.make_params(model, cfg, raw_key, device)
     out["change_norms"] = np.asarray(checks.diff_norms(p, p0)).tolist()
     return out

@@ -3,8 +3,10 @@ whatever degree the planner gives the sequences."""
 import pytest
 
 import flops
+import harness
 import traffic
 
+DENSE = harness.model_of({"model": "dense_gqa"})
 CFG = {"n_layers": 2, "d_model": 8, "n_heads": 2, "kv_heads": 1,
        "head_dim": 4, "d_ff": 16, "vocab": 32, "activation": "swiglu"}
 
@@ -20,8 +22,8 @@ def test_hand_count():
     # SwiGLU 3*8*16 = 384; two layers and the 8x32 head
     per_token = 6 * (2 * (192 + 384) + 8 * 32)
     per_pair = 12 * 2 * 4 * 2
-    assert flops.token_flops(CFG) == per_token
-    assert flops.step_flops(CFG, [causal, mixed]) == (
+    assert DENSE.token_flops(CFG) == per_token
+    assert flops.step_flops(DENSE, CFG, [causal, mixed]) == (
         11 * per_token + 39 * per_pair)
 
 
@@ -33,8 +35,6 @@ def test_same_count_at_any_degree(ranks):
     from repro.api.engine import demo_cost_model
     from repro.configs import get_config
 
-    import harness
-
     mix = traffic.load(harness.HERE / "traffic" / "openvid.json")
     seqs, toks = traffic.step(mix, 1000, 4, 0)
     batch = harness.ragged(seqs, toks, "vision")
@@ -45,6 +45,7 @@ def test_same_count_at_any_degree(ranks):
     plan = strat.plan(batch.infos)
     degrees = [g.degree for mb in plan.micro_batches for g in mb.groups]
     assert max(degrees) == ranks
-    planned = sum(flops.step_flops(CFG, [seqs[i] for i in g.seq_ids])
+    planned = sum(flops.step_flops(DENSE, CFG,
+                                   [seqs[i] for i in g.seq_ids])
                   for mb in plan.micro_batches for g in mb.groups)
-    assert planned == flops.step_flops(CFG, seqs)
+    assert planned == flops.step_flops(DENSE, CFG, seqs)

@@ -28,21 +28,23 @@ def _run(spec, seed=3):
 def test_weights_have_the_programs_layout():
     from repro.models.model import init_params
 
-    cfg = tiny_cell.spec()["cfg"]
+    spec = tiny_cell.spec()
+    cfg, model = spec["cfg"], spec["model"]
     ours = jax.eval_shape(lambda: model_init.make_params(
-        cfg, np.zeros(2, np.uint32)))
+        model, cfg, np.zeros(2, np.uint32)))
     theirs = jax.eval_shape(lambda: init_params(
-        jax.random.PRNGKey(0), harness.program_config(cfg)))
+        jax.random.PRNGKey(0), model.program_config(cfg)))
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
     assert jax.tree.map(lambda a: (a.shape, a.dtype), ours) == \
         jax.tree.map(lambda a: (a.shape, a.dtype), theirs)
 
 
 def test_weights_come_from_the_seed():
-    cfg = tiny_cell.spec()["cfg"]
-    a = model_init.make_params(cfg, harness.traffic.weight_key(7))
-    b = model_init.make_params(cfg, harness.traffic.weight_key(7))
-    c = model_init.make_params(cfg, harness.traffic.weight_key(8))
+    spec = tiny_cell.spec()
+    cfg, model = spec["cfg"], spec["model"]
+    a = model_init.make_params(model, cfg, harness.traffic.weight_key(7))
+    b = model_init.make_params(model, cfg, harness.traffic.weight_key(7))
+    c = model_init.make_params(model, cfg, harness.traffic.weight_key(8))
     assert all(np.array_equal(x, y) for x, y in
                zip(jax.tree.leaves(a), jax.tree.leaves(b)))
     assert not np.array_equal(a["embed"], c["embed"])

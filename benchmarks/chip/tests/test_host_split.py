@@ -1,6 +1,6 @@
-"""The readers of the executor's split of `collect` (`collect.wait`,
-`collect.d2h`, `collect.sum`, `grad_upload`), on synthetic runs and on
-the recorded run of a program from before the split."""
+"""The reader of the executor's `collect.sum` spans (`grad_host_sum_s`),
+on synthetic runs and on the recorded run of a program from before the
+executor split `collect` into spans of its parts."""
 import json
 from pathlib import Path
 
@@ -8,8 +8,10 @@ import pytest
 
 import harness
 
-SPANS = {"grad_wait_s": "collect.wait", "grad_d2h_s": "collect.d2h",
-         "grad_host_sum_s": "collect.sum", "grad_upload_s": "grad_upload"}
+SPANS = {"grad_host_sum_s": "collect.sum"}
+#: the other spans a split `collect` has held (waiting on the device,
+#: gradients to the host, the mean back to the device)
+OTHERS = ("collect.wait", "collect.d2h", "grad_upload")
 DATA = Path(__file__).resolve().parent / "data" / "trace"
 
 
@@ -22,7 +24,7 @@ def _run(spans, steps=2):
 @pytest.mark.parametrize("metric", sorted(SPANS))
 def test_sums_its_spans_per_step(metric):
     span = SPANS[metric]
-    others = [(n, 9.0) for n in SPANS.values() if n != span]
+    others = [(n, 9.0) for n in OTHERS]
     run = _run([("collect", 20.0), (span, 1.5), (span, 2.5),
                 (span, 0.5)] + others)
     assert harness.reader(harness.HERE, metric)(run) == pytest.approx(2.25)
@@ -30,7 +32,7 @@ def test_sums_its_spans_per_step(metric):
 
 @pytest.mark.parametrize("metric", sorted(SPANS))
 def test_zero_when_the_split_lacks_its_span(metric):
-    others = [(n, 9.0) for n in SPANS.values() if n != SPANS[metric]]
+    others = [(n, 9.0) for n in OTHERS]
     run = _run([("collect", 30.0)] + others)
     assert harness.reader(harness.HERE, metric)(run) == 0.0
 
@@ -57,3 +59,6 @@ def test_listed_in_the_benchmark():
             "program_span", "executor host side", "tokens_per_s",
             ["ivl2b-openvid-1c"])
         assert (m["unit"], m["better"]) == ("s", "lower")
+    for pruned in ("grad_wait_s", "grad_d2h_s", "grad_upload_s"):
+        assert pruned not in listed
+        assert not (harness.HERE / "metrics" / f"{pruned}.py").exists()

@@ -11,10 +11,12 @@ DATA = Path(__file__).resolve().parent / "data" / "tiny"
 
 def spec(limits=None):
     bench = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
+    cfg = json.loads((DATA / "tiny.json").read_text())
     return {
         "cell": {"name": "tiny-cell", "config": "tiny",
                  "traffic": "tinyvid", "chips": 1},
-        "cfg": json.loads((DATA / "tiny.json").read_text()),
+        "cfg": cfg,
+        "model": harness.model_of(cfg),
         "mix": traffic.load(DATA / "tinyvid.json"),
         "limits": limits or json.loads(
             (DATA / "limits.json").read_text())["limits"],

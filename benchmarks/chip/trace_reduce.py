@@ -9,8 +9,8 @@ the host plane. On each chip in use (planes `/device:TPU:<n>`):
 * programs: the summed duration of the `XLA Modules` line's events per
   jitted function, named as `jax.jit` names them (`jit_<fn>(<id>)` ->
   `<fn>`), summed over the chips;
-* device_ops: the ops that took the most time, summed by name, each
-  less the ops nested inside it;
+* ops: every op's self time (less the ops nested inside it), summed
+  by name over the chips; device_ops: the `TOP` that took the most;
 * idle_gaps: the longest stretches between busy intervals on the first
   chip, each named by the innermost host span around its midpoint: the
   benchmark's `bench.*` annotations, and the program's own spans when
@@ -160,6 +160,7 @@ def reduce(profile, chips: int,
         "window_s": (window[1] - window[0]) * 1e-9,
         "busy_s": sum(busy_ns) / len(busy_ns) * 1e-9,
         "programs": dict(programs),
+        "ops": dict(ops),
         "device_ops": [[n, s] for n, s in sorted(
             ops.items(), key=lambda kv: -kv[1])[:TOP]],
         "idle_gaps": [[n, s] for n, s in gaps[:TOP]],
