@@ -24,7 +24,8 @@ from typing import Any, Dict, Iterable, Iterator, List, Optional, Union
 
 from ..configs import get_config
 from ..configs.base import ModelConfig
-from ..core.cost_model import CostModel, SeqInfo, analytic_coeffs
+from ..core.cost_model import (CostModel, SeqInfo, analytic_coeffs,
+                               model_costs)
 from ..core.executor import DHPExecutor
 from ..core.scheduler import ExecutionPlan, diff_plans
 from ..data.pipeline import HeterogeneousLoader, RaggedBatch
@@ -139,7 +140,7 @@ def demo_cost_model(cfg: ModelConfig) -> CostModel:
         analytic_coeffs(
             hidden=cfg.d_model, n_layers=cfg.n_layers,
             n_heads=max(cfg.n_heads, 1), kv_heads=max(cfg.kv_heads, 1),
-            ffn=max(cfg.d_ff, 1), vocab=cfg.vocab),
+            ffn=max(cfg.d_ff, 1), vocab=cfg.vocab, **model_costs(cfg)),
         m_ms=0.0, m_token=1.0)
     return CostModel(coeffs)
 

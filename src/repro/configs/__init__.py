@@ -21,6 +21,7 @@ _MODULES = {
     # the paper's own workloads
     "internvl3-2b": "internvl3_2b",
     "qwen3vl-8b": "qwen3vl_8b",
+    "kimi-vl-a3b": "kimi_vl_a3b",
 }
 
 ASSIGNED_ARCHS = list(_MODULES)[:10]

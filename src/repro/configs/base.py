@@ -3,7 +3,8 @@
 One frozen dataclass describes every architecture in the assigned pool;
 family-specific knobs live in optional sub-fields. `ModelConfig.reduced()`
 derives the CPU smoke-test variant (2 layers, d_model <= 512, <= 4
-experts) required per architecture.
+experts held; 3 layers where a dense layer leads the expert layers)
+required per architecture.
 """
 from __future__ import annotations
 
@@ -21,6 +22,40 @@ class MoECfg:
     dispatch: str = "einsum"  # einsum (one-hot baseline) | sort (O(T·k·D))
     dispatch_group: int = 8192  # sort: tokens per shard-local dispatch
                                 # group (0 = one global group)
+    # -- the dropless expert layer (scoring="sigmoid", DeepSeek-V3's
+    # -- noaux_tc): it holds `n_experts` of the router's `router_experts`,
+    # -- from `expert_offset` on, and computes their part for every pair
+    # -- routed to them; the capacity fields above do not apply
+    router_experts: Optional[int] = None  # router width; None: n_experts
+    expert_offset: int = 0    # index of the first expert held here
+    n_shared: int = 0         # shared experts: one SwiGLU n_shared x wide
+    scoring: str = "softmax"  # softmax (capacity) | sigmoid (dropless)
+    route_scale: float = 1.0  # routed_scaling_factor
+    first_dense: int = 0      # leading dense layers (d_ff wide)
+
+    @property
+    def n_routed(self) -> int:
+        """Experts the router scores (all of them, held here or not)."""
+        return self.router_experts or self.n_experts
+
+    @property
+    def dropless(self) -> bool:
+        return self.scoring == "sigmoid"
+
+
+@dataclasses.dataclass(frozen=True)
+class MLACfg:
+    """DeepSeek-V2 multi-head latent attention with a full q projection
+    (`q_lora_rank` null): K and V come from one `kv_lora_rank` latent
+    (RMS-normed) and a rope key shared by all heads."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,6 +113,7 @@ class ModelConfig:
     tie_embeddings: bool = False
 
     moe: Optional[MoECfg] = None
+    mla: Optional[MLACfg] = None
     ssm: Optional[SSMCfg] = None
     hybrid: Optional[HybridCfg] = None
     encdec: Optional[EncDecCfg] = None
@@ -116,7 +152,9 @@ class ModelConfig:
         while n_heads % kv:
             kv -= 1
         kw = dict(
-            n_layers=2 if self.family != "hybrid" else 3,
+            n_layers=(3 if self.family == "hybrid"
+                      else 2 + min(self.moe.first_dense, 1) if self.moe
+                      else 2),
             d_model=d_model,
             n_heads=n_heads,
             kv_heads=kv,
@@ -130,7 +168,17 @@ class ModelConfig:
         if self.moe:
             kw["moe"] = dataclasses.replace(
                 self.moe, n_experts=4, top_k=min(self.moe.top_k, 2),
-                expert_ff=min(self.moe.expert_ff, 256))
+                expert_ff=min(self.moe.expert_ff, 256),
+                # the dropless layer holds a real share: 4 of 8
+                router_experts=8 if self.moe.dropless else None,
+                expert_offset=0, first_dense=min(self.moe.first_dense, 1))
+        if self.mla:
+            m = self.mla
+            kw["mla"] = dataclasses.replace(
+                m, kv_lora_rank=min(m.kv_lora_rank, 64),
+                qk_nope_head_dim=min(m.qk_nope_head_dim, 32),
+                qk_rope_head_dim=min(m.qk_rope_head_dim, 16),
+                v_head_dim=min(m.v_head_dim, 32))
         if self.ssm:
             kw["ssm"] = dataclasses.replace(
                 self.ssm, d_state=16, head_dim=32, chunk=32)

@@ -13,6 +13,7 @@ from .allocator import (Allocation, IncrementalAllocator, allocate,
                         allocate_reference, evaluate_degrees)
 from .cost_model import (CostCoeffs, CostModel, Hardware, MMSequence,
                          ModalitySpan, SeqInfo, analytic_coeffs,
+                         model_costs,
                          as_seq_infos, slice_spans, spans_eta,
                          synthesize_spans)
 from .dataset_profiles import PROFILES, DatasetProfile, get_profile
@@ -35,6 +36,7 @@ __all__ = [
     "allocate_bruteforce", "allocate_many", "allocate_reference",
     "evaluate_degrees",
     "CostCoeffs", "CostModel", "Hardware", "SeqInfo", "analytic_coeffs",
+    "model_costs",
     "MMSequence", "ModalitySpan", "as_seq_infos", "slice_spans",
     "spans_eta", "synthesize_spans",
     "DatasetProfile", "PROFILES", "get_profile",

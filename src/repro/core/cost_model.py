@@ -362,23 +362,31 @@ def analytic_coeffs(
     mfu: float = 0.45,
     params: float | None = None,
     zero_shards: int = 64,
+    lin_flops_per_tok: float | None = None,
+    attn_flops_per_tok2: float | None = None,
+    kv_hop_bytes: float | None = None,
 ) -> CostCoeffs:
     """Roofline-derived coefficients for a transformer of the given shape.
 
     Used when no measured profile is available (the Profiler refines these
     by fitting measured samples, reproducing the paper's <8% error claim).
-    Training step FLOPs ~ 3x forward (fwd + 2x bwd).
+    Training step FLOPs ~ 3x forward (fwd + 2x bwd). The per-token
+    linear FLOPs, attention FLOPs per (causal) token pair and K/V bytes a
+    token per ring hop default to a dense GQA block of the given shape;
+    `model_costs(cfg)` gives them for any `ModelConfig`.
     """
     head_dim = hidden // n_heads
     # attention: QK^T + AV = 2 * 2 * L^2 * hidden FLOPs per layer (causal
     # halves it; eta interpolates back up -> fold the 1/2 into a1).
-    attn_flops_per_tok2 = 3 * 2 * 2 * hidden * n_layers * 0.5
+    if attn_flops_per_tok2 is None:
+        attn_flops_per_tok2 = 3 * 2 * 2 * hidden * n_layers * 0.5
     # linear: qkv + o + mlp (+ lm head amortized)
-    lin_flops_per_tok = 3 * 2 * (
-        hidden * (hidden + 2 * kv_heads * head_dim)  # qkv
-        + hidden * hidden                             # out proj
-        + 3 * hidden * ffn                            # swiglu mlp
-    ) * n_layers + 3 * 2 * hidden * vocab
+    if lin_flops_per_tok is None:
+        lin_flops_per_tok = 3 * 2 * (
+            hidden * (hidden + 2 * kv_heads * head_dim)  # qkv
+            + hidden * hidden                             # out proj
+            + 3 * hidden * ffn                            # swiglu mlp
+        ) * n_layers + 3 * 2 * hidden * vocab
     eff = peak_flops * mfu
     n_params = params if params is not None else (
         n_layers * (hidden * (hidden + 2 * kv_heads * head_dim)
@@ -390,7 +398,9 @@ def analytic_coeffs(
     # ZeRO-3: params+grads+optimizer(fp32 m,v,master) / shards
     m_ms = n_params * (2 + 2 + 12) / zero_shards
     # ring comm: 2 (K and V) * kv_heads*head_dim * bytes per token per hop
-    a3 = 2 * kv_heads * head_dim * dtype_bytes / 1e9  # GB per token-hop
+    if kv_hop_bytes is None:
+        kv_hop_bytes = 2 * kv_heads * head_dim * dtype_bytes
+    a3 = kv_hop_bytes / 1e9  # GB per token-hop
     return CostCoeffs(
         a1=attn_flops_per_tok2 / eff,
         a2=lin_flops_per_tok / eff,
@@ -400,3 +410,49 @@ def analytic_coeffs(
         m_token=m_token,
         m_ms=m_ms,
     )
+
+
+def model_costs(cfg, dtype_bytes: int = 2) -> dict:
+    """`analytic_coeffs`' per-token linear FLOPs, attention FLOPs per
+    token pair, K/V hop bytes and parameter count, from a `ModelConfig`
+    whose block the dense GQA default does not describe: latent
+    attention (q/k heads of dn + dr, v heads of dv; the ring carries the
+    up-projected K and V), leading dense layers beside expert layers,
+    and an expert layer's active width (shared experts, the router, and
+    top_k x held / routed of the experts: the pairs a chip computes).
+    Empty for every other config, whose coefficients stay the dense
+    formula's."""
+    moe = cfg.moe if cfg.moe is not None and cfg.moe.dropless else None
+    if cfg.mla is None and moe is None:
+        return {}
+    D, H, L = cfg.d_model, cfg.n_heads, cfg.n_layers
+    if cfg.mla:
+        m = cfg.mla
+        qk, dv = m.qk_head_dim, m.v_head_dim
+        attn_params = (D * H * qk + D * (m.kv_lora_rank + m.qk_rope_head_dim)
+                       + m.kv_lora_rank * H * (m.qk_nope_head_dim + dv)
+                       + H * dv * D)
+        score_width = H * (qk + dv)
+        kv_width = H * (qk + dv)
+    else:
+        dh = cfg.resolved_head_dim
+        attn_params = D * dh * (2 * H + 2 * cfg.kv_heads)
+        score_width = 2 * H * dh
+        kv_width = 2 * cfg.kv_heads * dh
+    dense_ffn = 3 * D * cfg.d_ff
+    n_lead = moe.first_dense if moe else 0
+    if moe:
+        expert = 3 * D * moe.expert_ff
+        active = (moe.n_shared * expert + D * moe.n_routed
+                  + moe.top_k * moe.n_experts / moe.n_routed * expert)
+        held = (moe.n_shared + moe.n_experts) * expert + D * moe.n_routed
+    else:
+        active = held = dense_ffn
+    layers_lin = L * attn_params + n_lead * dense_ffn + (L - n_lead) * active
+    return {
+        "lin_flops_per_tok": 3 * 2 * layers_lin + 3 * 2 * D * cfg.vocab,
+        "attn_flops_per_tok2": 3 * 2 * score_width * L * 0.5,
+        "kv_hop_bytes": kv_width * dtype_bytes,
+        "params": (L * attn_params + n_lead * dense_ffn
+                   + (L - n_lead) * held + 2 * D * cfg.vocab),
+    }

@@ -82,27 +82,35 @@ GRAD_STEP_MODULE = "jit_fwd_bwd"
 GRAD_ACCUM_MODULE = "jit_accumulate_grads"
 
 
+def _add_tables(a, b):
+    """The sum of two counting tables, None for none yet; tables by name
+    (a dict) add name by name."""
+    if a is None or b is None:
+        return b if a is None else a
+    if isinstance(a, dict):
+        return {k: _add_tables(a.get(k), b.get(k)) for k in a.keys() | b}
+    return a + b
+
+
 @partial(jax.jit, donate_argnums=0)
 def accumulate_grads(acc, out, w, total=None):
     """One group's share of a run's token-weighted mean, in float32 on
     the device: `acc` + `out` cast to float32 (exact from bf16) and
     times the group's token weight `w`, per leaf of (loss, grads), and
-    the modality aux table added unweighted. `acc` None starts the sums
-    (the first group); `total` divides the sums after the last group's
-    add. `out` is the grad step's (loss, grads[, aux]); the accumulator
-    is (loss, grads, aux or None), donated, so it is updated in place."""
+    the counting tables (the modality aux table, or tables by name)
+    added unweighted. `acc` None starts the sums (the first group);
+    `total` divides the sums after the last group's add. `out` is the
+    grad step's (loss, grads[, aux]); the accumulator is (loss, grads,
+    aux or None), donated, so it is updated in place."""
     loss, grads = out[0], out[1]
     aux = out[2] if len(out) > 2 else None
     f32 = lambda a: a.astype(jnp.float32) * w
     if acc is None:
         acc = (f32(loss), jax.tree.map(f32, grads), aux)
     else:
-        acc_aux = acc[2]
-        if aux is not None:
-            acc_aux = aux if acc_aux is None else acc_aux + aux
         acc = (acc[0] + f32(loss),
                jax.tree.map(lambda a, g: a + f32(g), acc[1], grads),
-               acc_aux)
+               _add_tables(acc[2], aux))
     if total is None:
         return acc
     return (acc[0] / total, jax.tree.map(lambda a: a / total, acc[1]),
@@ -173,8 +181,13 @@ class DHPExecutor:
         attention + masked-loss path; pure-causal groups keep the
         pre-span executable (and its exact numerics). Span-bearing
         steps return a [n_classes, 2] (nll_sum, label_count) aux table
-        per MODALITY_CLASSES entry, reduced over the cp axis."""
+        per MODALITY_CLASSES entry, reduced over the cp axis. A model
+        with dropless expert layers returns its tables by name instead:
+        "routes", the pairs routed to each held expert of each expert
+        layer (int32 [layers, experts], summed over the cp axis), and
+        "modality" in span-bearing steps."""
         cfg = self.cfg_cp
+        routed = cfg.moe is not None and cfg.moe.dropless
 
         def build():
             pspec = P()     # params replicated on the sub-mesh (demo TP=1)
@@ -187,13 +200,28 @@ class DHPExecutor:
             bspec = {k: P(None, "cp") for k in keys}
 
             def shard_loss(params, batch):
+                if routed:
+                    logits, _, routes = forward(params, cfg, batch,
+                                                with_routes=True)
+                    tables = {"routes": jax.lax.stop_gradient(
+                        jax.lax.psum(routes, "cp"))}
+                    loss, aux = span_loss(logits, batch) if with_spans \
+                        else (causal_loss(logits, batch), None)
+                    if aux is not None:
+                        tables["modality"] = aux
+                    return loss, tables
                 logits, _ = forward(params, cfg, batch)
                 if not with_spans:
-                    s, c = _masked_nll(logits, batch["labels"],
-                                       batch["mask"])
-                    s = jax.lax.psum(s, "cp")
-                    c = jax.lax.psum(c, "cp")
-                    return s / jnp.maximum(c, 1.0)
+                    return causal_loss(logits, batch)
+                return span_loss(logits, batch)
+
+            def causal_loss(logits, batch):
+                s, c = _masked_nll(logits, batch["labels"], batch["mask"])
+                s = jax.lax.psum(s, "cp")
+                c = jax.lax.psum(c, "cp")
+                return s / jnp.maximum(c, 1.0)
+
+            def span_loss(logits, batch):
                 nll = _token_nll(logits, batch["labels"])
                 lm = batch["loss_mask"]
                 s = jax.lax.psum((nll * lm).sum(), "cp")
@@ -210,16 +238,21 @@ class DHPExecutor:
                 # would not transpose through shard_map
                 return s / jnp.maximum(c, 1.0), jax.lax.stop_gradient(aux)
 
+            has_aux = with_spans or routed
+
             def loss_of(params, batch):
                 # params enter shard_map replicated (demo TP=1)
-                out_specs = (P(), P()) if with_spans else P()
+                out_specs = (P(), P()) if has_aux else P()
+                # the grouped-matmul kernel states no varying axes for
+                # its output: a routed model's step is not vma-checked
+                kw = {"check_vma": False} if routed else {}
                 return jax.shard_map(
                     shard_loss, mesh=mesh,
-                    in_specs=(pspec, bspec), out_specs=out_specs,
+                    in_specs=(pspec, bspec), out_specs=out_specs, **kw,
                 )(params, batch)
 
             def fwd_bwd(params, batch):
-                if with_spans:
+                if has_aux:
                     (loss, aux), grads = jax.value_and_grad(
                         loss_of, has_aux=True)(params, batch)
                     return loss, grads, aux
@@ -428,13 +461,25 @@ class DHPExecutor:
         agg["padding_efficiency"] = (
             agg["real_tokens"] / max(agg["padded_tokens"], 1))
         loss, grads, aux = acc
+        tables = {}
         if aux is not None:
             # the one host read of the run, after the last dispatch
-            aux = np.asarray(aux, np.float64)
+            aux = jax.device_get(aux)
+            tables = aux if isinstance(aux, dict) else {"modality": aux}
+        if "modality" in tables:
+            aux = np.asarray(tables["modality"], np.float64)
             agg["modality_loss"] = {
                 name: float(aux[k, 0] / aux[k, 1])
                 for k, name in enumerate(MODALITY_CLASSES)
                 if aux[k, 1] > 0}
+        if "routes" in tables:
+            routes = np.asarray(tables["routes"], np.int64)
+            agg["moe_routed_pairs"] = int(routes.sum())
+            agg["moe_max_expert_pairs"] = int(routes.max())
+            if tr.enabled:
+                tr.counter("moe.routes", {
+                    "routed_pairs": agg["moe_routed_pairs"],
+                    "max_expert_pairs": agg["moe_max_expert_pairs"]})
         self.last_run_stats = agg
         if tr.enabled:
             tr.complete("run_plan", t_run,

@@ -1,4 +1,5 @@
-"""Attention: GQA projections + three mask modes x three implementations.
+"""Attention: GQA projections (and MLA, latent K/V) + three mask modes x
+three implementations.
 
 Implementations:
   * reference — full score matrix (smoke tests, tiny shapes).
@@ -24,9 +25,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .layers import apply_rope, dense_init
+from .layers import apply_rope, dense_init, init_rmsnorm, rms_norm
 
 NEG_INF = -1e30
+#: epsilon of MLA's latent RMSNorm (DeepSeek-V2's kv_a_layernorm, built
+#: with its modelling code's default; config.json does not give it)
+LATENT_EPS = 1e-6
 
 
 def init_attention(key, d_model: int, n_heads: int, kv_heads: int,
@@ -118,7 +122,7 @@ def attn_reference(q, k, v, *, mode: str, window=None, q_offset=0,
         # zeros like every other packed implementation, instead of the
         # uniform softmax over an all-NEG_INF row
         o = jnp.where((seg >= 0)[:, :, None, None, None], o, 0.0)
-    return o.reshape(B, S, H, D).astype(q.dtype)
+    return o.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
 
 
 def _kv_blocks(k, v, chunk):
@@ -216,7 +220,7 @@ def _attn_chunked_fwd_impl(q, k, v, seg_q, seg_k, span_q, span_k, mode,
 
     m0 = jnp.full((B, S, Hkv, G), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, S, Hkv, G), jnp.float32)
-    a0 = jnp.zeros((B, S, Hkv, G, D), jnp.float32)
+    a0 = jnp.zeros((B, S, Hkv, G, v.shape[-1]), jnp.float32)
 
     def body(carry, blk):
         m, l, acc = carry
@@ -238,7 +242,7 @@ def _attn_chunked_fwd_impl(q, k, v, seg_q, seg_k, span_q, span_k, mode,
         body, (m0, l0, a0), (kb, vb, jnp.arange(n_blk), segb, spanb))
     lse = m + jnp.log(jnp.maximum(l, 1e-30))           # [B,S,Hkv,G]
     o = acc / jnp.maximum(l[..., None], 1e-30)
-    out = o.reshape(B, S, H, D).astype(q.dtype)
+    out = o.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
     return out, lse
 
 
@@ -260,9 +264,10 @@ def _attn_chunked_bwd(mode, window, q_offset, kv_offset, chunk, res, g):
     segb = _table_chunks(seg_k, chunk, n_blk)
     spanb = _table_chunks(span_k, chunk, n_blk)
     scale = 1.0 / math.sqrt(D)
+    Dv = v.shape[-1]
     qg = q.reshape(B, S, Hkv, G, D).astype(jnp.float32)
-    gg = g.reshape(B, S, Hkv, G, D).astype(jnp.float32)
-    og = out.reshape(B, S, Hkv, G, D).astype(jnp.float32)
+    gg = g.reshape(B, S, Hkv, G, Dv).astype(jnp.float32)
+    og = out.reshape(B, S, Hkv, G, Dv).astype(jnp.float32)
     delta = jnp.sum(gg * og, axis=-1)                   # [B,S,Hkv,G]
     qpos = q_offset + jnp.arange(S)
 
@@ -286,7 +291,7 @@ def _attn_chunked_bwd(mode, window, q_offset, kv_offset, chunk, res, g):
     dq, (dkb, dvb) = jax.lax.scan(
         body, dq0, (kb, vb, jnp.arange(n_blk), segb, spanb))
     dk = dkb.transpose(1, 0, 2, 3, 4).reshape(B, n_blk * chunk, Hkv, D)
-    dv = dvb.transpose(1, 0, 2, 3, 4).reshape(B, n_blk * chunk, Hkv, D)
+    dv = dvb.transpose(1, 0, 2, 3, 4).reshape(B, n_blk * chunk, Hkv, Dv)
     zero_like = lambda t: None if t is None else jnp.zeros_like(t)  # noqa: E731
     return (dq.reshape(B, S, H, D).astype(q.dtype),
             dk[:, :T].astype(k.dtype), dv[:, :T].astype(v.dtype),
@@ -445,42 +450,100 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, kv_heads: int,
     else:
         k, v = cross_kv
         mode = "full"
-
-    if cp_axis is not None and cross_kv is None:
-        # Ring-style context parallelism (inside shard_map): the
-        # sequence axis of x/positions/segment_ids is sharded over
-        # `cp_axis`; the segment table travels with each KV hop.
-        from ..parallel.ring_attention import ring_attention
-        o = ring_attention(q, k, v, positions, axis_name=cp_axis,
-                           mode=mode, window=window,
-                           q_seg=segment_ids, q_span=span_ids)
-        out = o.reshape(B, S, n_heads * head_dim) @ params["wo"]
-        return (out, (k, v)) if return_kv else out
-
-    if impl == "pallas":
-        if segment_ids is not None or span_ids is not None:
-            from ..kernels.ops import flash_attention_packed
-            seg = (segment_ids if segment_ids is not None
-                   else jnp.zeros((B, S), jnp.int32))
-            o = flash_attention_packed(q, k, v, seg, span_ids=span_ids,
-                                       mode=mode, window=window)
-        else:
-            from ..kernels.ops import flash_attention
-            o = flash_attention(q, k, v, mode=mode, window=window)
-    elif impl == "reference":
-        o = attn_reference(q, k, v, mode=mode, window=window,
-                           segment_ids=segment_ids, span_ids=span_ids)
-    elif (mode == "sliding" and cross_kv is None and impl == "chunked"
-          and segment_ids is None and span_ids is None):
-        o = attn_banded(q, k, v, window=window, chunk=min(attn_chunk, 512))
-    else:
-        o = attn_chunked(q, k, v, mode=mode, window=window,
-                         chunk=attn_chunk, segment_ids=segment_ids,
-                         span_ids=span_ids)
+    o = _attend(q, k, v, positions, mode=mode, window=window, impl=impl,
+                cp_axis=None if cross_kv is not None else cp_axis,
+                attn_chunk=attn_chunk, segment_ids=segment_ids,
+                span_ids=span_ids)
     out = o.reshape(B, S, n_heads * head_dim) @ params["wo"]
     if return_kv:
         return out, (k, v)
     return out
+
+
+def _attend(q, k, v, positions, *, mode, window, impl, cp_axis,
+            attn_chunk, segment_ids, span_ids):
+    """The attention core `impl` (or the ring over `cp_axis`) on
+    projected q [B,S,H,D], k [B,T,Hkv,D], v [B,T,Hkv,Dv]: o [B,S,H,Dv]."""
+    B, S = q.shape[:2]
+    if cp_axis is not None:
+        # Ring-style context parallelism (inside shard_map): the
+        # sequence axis of x/positions/segment_ids is sharded over
+        # `cp_axis`; the segment table travels with each KV hop.
+        from ..parallel.ring_attention import ring_attention
+        return ring_attention(q, k, v, positions, axis_name=cp_axis,
+                              mode=mode, window=window,
+                              q_seg=segment_ids, q_span=span_ids)
+    if impl == "pallas":
+        if q.shape[-1] != v.shape[-1]:
+            raise NotImplementedError(
+                "the Pallas flash kernels take one head dim for q, k and v")
+        if segment_ids is not None or span_ids is not None:
+            from ..kernels.ops import flash_attention_packed
+            seg = (segment_ids if segment_ids is not None
+                   else jnp.zeros((B, S), jnp.int32))
+            return flash_attention_packed(q, k, v, seg, span_ids=span_ids,
+                                          mode=mode, window=window)
+        from ..kernels.ops import flash_attention
+        return flash_attention(q, k, v, mode=mode, window=window)
+    if impl == "reference":
+        return attn_reference(q, k, v, mode=mode, window=window,
+                              segment_ids=segment_ids, span_ids=span_ids)
+    if (mode == "sliding" and impl == "chunked"
+            and segment_ids is None and span_ids is None):
+        return attn_banded(q, k, v, window=window,
+                           chunk=min(attn_chunk, 512))
+    return attn_chunked(q, k, v, mode=mode, window=window,
+                        chunk=attn_chunk, segment_ids=segment_ids,
+                        span_ids=span_ids)
+
+
+# --------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2 MLA, full q projection)
+# --------------------------------------------------------------------------
+def init_mla(key, d_model: int, n_heads: int, mla, dtype) -> dict:
+    """wq [D, H*(dn+dr)]; wkv_a [D, r+dr] (the latent and the shared rope
+    key); kv_norm, the latent's RMSNorm; wkv_b [r, H*(dn+dv)] (each
+    head's k_nope then v); wo [H*dv, D]."""
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    r, dn, dv = mla.kv_lora_rank, mla.qk_nope_head_dim, mla.v_head_dim
+    return {
+        "wq": dense_init(kq, d_model, n_heads * mla.qk_head_dim, dtype),
+        "wkv_a": dense_init(ka, d_model, r + mla.qk_rope_head_dim, dtype),
+        "kv_norm": init_rmsnorm(r, dtype),
+        "wkv_b": dense_init(kb, r, n_heads * (dn + dv), dtype),
+        "wo": dense_init(ko, n_heads * dv, d_model, dtype),
+    }
+
+
+def mla_attention(params: dict, x: jax.Array, *, n_heads: int, mla,
+                  rope_theta: float, positions=None, mode: str = "causal",
+                  window: Optional[int] = None, impl: str = "chunked",
+                  cp_axis: Optional[str] = None, attn_chunk: int = 1024,
+                  segment_ids=None, span_ids=None):
+    """q = x·wq, per head [nope ‖ rope], rope on the rope dims;
+    [c, k_rope] = x·wkv_a; c = RMSNorm(c); one k_rope, roped, for all
+    heads; [k_nope ‖ v] = c·wkv_b per head; k = [k_nope ‖ k_rope];
+    scores scaled by 1/sqrt(dn + dr); o·wo. The up-projected K and V go
+    to the core (and ride the ring's hops). Masks as in `attention`."""
+    B, S, _ = x.shape
+    r, dn, dr = mla.kv_lora_rank, mla.qk_nope_head_dim, mla.qk_rope_head_dim
+    dv = mla.v_head_dim
+    if positions is None:
+        positions = jnp.arange(S)[None, :].repeat(B, 0)
+    q = (x @ params["wq"]).reshape(B, S, n_heads, dn + dr)
+    q = jnp.concatenate(
+        [q[..., :dn], apply_rope(q[..., dn:], positions, rope_theta)], -1)
+    kv_a = x @ params["wkv_a"]
+    c = rms_norm(params["kv_norm"], kv_a[..., :r], LATENT_EPS)
+    k_rope = apply_rope(kv_a[..., r:].reshape(B, S, 1, dr), positions,
+                        rope_theta)
+    kv = (c @ params["wkv_b"]).reshape(B, S, n_heads, dn + dv)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope, (B, S, n_heads, dr))], -1)
+    o = _attend(q, k, kv[..., dn:], positions, mode=mode, window=window,
+                impl=impl, cp_axis=cp_axis, attn_chunk=attn_chunk,
+                segment_ids=segment_ids, span_ids=span_ids)
+    return o.reshape(B, S, n_heads * dv) @ params["wo"]
 
 
 def project_qkv_decode(params, x1, *, n_heads, kv_heads, head_dim,

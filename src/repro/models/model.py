@@ -2,6 +2,7 @@
 
   init_params(key, cfg)                        -> params pytree
   forward(params, cfg, batch)                  -> (logits [B,S,V], aux)
+  forward(..., with_routes=True)               -> (logits, aux, routes)
   init_cache(cfg, batch_size, cache_len)       -> decode cache pytree
   prefill_cache(params, cfg, batch, cache_len) -> cache  (audio cross-KV)
   decode_step(params, cfg, cache, tokens [B])  -> (logits [B,V], cache)
@@ -64,8 +65,14 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
         params["head"] = dense_init(k_head, cfg.d_model, cfg.vocab, dt)
 
     if cfg.family in ("dense", "moe", "ssm", "vlm"):
+        n_lead = cfg.moe.first_dense if cfg.moe else 0
+        if n_lead:
+            # a dense FFN leads the expert layers: its own stack
+            k_lead, k_layers = jax.random.split(k_layers)
+            params["dense_layers"] = init_stack(
+                k_lead, cfg, n_lead, _LAYER_INIT["dense"])
         params["layers"] = init_stack(
-            k_layers, cfg, cfg.n_layers, _LAYER_INIT[cfg.family])
+            k_layers, cfg, cfg.n_layers - n_lead, _LAYER_INIT[cfg.family])
     elif cfg.family == "hybrid":
         n_units, tail = hybrid_layout(cfg)
         ku, kt = jax.random.split(k_layers)
@@ -102,6 +109,14 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
     return params
 
 
+def _check_servable(cfg: ModelConfig) -> None:
+    if cfg.mla or (cfg.moe and cfg.moe.dropless):
+        raise NotImplementedError(
+            f"{cfg.arch_id}: serving (a KV or latent cache, and the "
+            f"dropless expert layer at decode) is not implemented; this "
+            f"model trains through Engine.train")
+
+
 # ==========================================================================
 # Embedding assembly (modality interleave)
 # ==========================================================================
@@ -124,8 +139,11 @@ def _head(params, cfg: ModelConfig, x) -> jax.Array:
 # ==========================================================================
 # Forward (train / prefill)
 # ==========================================================================
-def forward(params, cfg: ModelConfig, batch,
-            mode: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+def forward(params, cfg: ModelConfig, batch, mode: Optional[str] = None,
+            with_routes: bool = False):
+    """(logits, aux); `with_routes` adds the dropless expert layers'
+    routes: the pairs routed to each held expert, [layers, experts]
+    int32, or None where no layer routes."""
     if cfg.family == "audio":
         return _forward_audio(params, cfg, batch)
     x = _input_embeddings(params, cfg, batch)
@@ -140,19 +158,30 @@ def forward(params, cfg: ModelConfig, batch,
     # block (flatten_group / padded_batch produce it)
     span_ids = batch.get("modality_ids")
 
+    routes = None
     if cfg.family in ("dense", "moe", "ssm", "vlm"):
-        block = _BLOCK[cfg.family]
-        def body(p_l, h):
-            return block(p_l, h, cfg, mode=attn_mode, window=window,
-                         positions=positions, segment_ids=segment_ids,
-                         span_ids=span_ids)
-        x, aux = apply_stack(params["layers"], x, body, cfg.remat,
-                             cfg.scan_layers)
+        def stack(x, name, block):
+            def body(p_l, h):
+                return block(p_l, h, cfg, mode=attn_mode, window=window,
+                             positions=positions, segment_ids=segment_ids,
+                             span_ids=span_ids)
+            return apply_stack(params[name], x, body, cfg.remat,
+                               cfg.scan_layers)
+
+        lead = None
+        if "dense_layers" in params:
+            x, lead = stack(x, "dense_layers", _BLOCK["dense"])
+        x, aux, *ys = stack(x, "layers", _BLOCK[cfg.family])
+        if lead is not None:
+            aux = aux + lead
+        routes = ys[0] if ys else None
     elif cfg.family == "hybrid":
         x, aux = _hybrid_forward(params, cfg, x, positions, segment_ids,
                                  span_ids)
     else:
         raise ValueError(cfg.family)
+    if with_routes:
+        return _head(params, cfg, x), aux, routes
     return _head(params, cfg, x), aux
 
 
@@ -236,6 +265,7 @@ def prefill(params, cfg: ModelConfig, batch, cache_len: int | None = None):
     forward() + init_cache (logits-only prefill; see DESIGN.md).
     """
     assert cfg.family in ("dense", "moe", "vlm"), cfg.family
+    _check_servable(cfg)
     x = _input_embeddings(params, cfg, batch)
     B, S, _ = x.shape
     positions = batch.get("positions")
@@ -306,6 +336,7 @@ def prefill_chunk(params, cfg: ModelConfig, cache: Dict[str, Any],
     (callers chunking a padded final bucket pass their own pos).
     """
     assert cfg.family in ("dense", "moe", "vlm"), cfg.family
+    _check_servable(cfg)
     assert cfg.sliding_window is None, \
         "chunked prefill needs a non-rotating cache"
     from .attention import attn_prefill_chunk, project_qkv_decode  # noqa: F401
@@ -369,6 +400,7 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
                dtype=None) -> Dict[str, Any]:
     """cache_len = context capacity; sliding-window archs allocate only
     min(window, cache_len) slots (ring buffer)."""
+    _check_servable(cfg)
     dt = dtype or _dtype(cfg.param_dtype)
     cache: Dict[str, Any] = {"pos": jnp.zeros((), jnp.int32)}
     if cfg.family in ("dense", "moe", "vlm"):

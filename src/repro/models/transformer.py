@@ -8,6 +8,8 @@ regardless of depth — essential for the 512-device dry-runs of a
 Families:
   dense  — [attn + MLP] x L                     (llama3/glm4/chatglm3/minitron/pixtral LM)
   moe    — [attn + MoE-FFN] x L                 (granite, olmoe)
+           [MLA + MLP] x first_dense, then [MLA + dropless held-share
+           experts + shared experts] x the rest  (kimi-vl-a3b)
   ssm    — [mamba2 SSD] x L                     (mamba2-370m)
   hybrid — [(rec, rec, attn) + MLP each] x ...  (recurrentgemma)
   audio  — whisper enc(full attn) + dec(causal + cross)
@@ -37,13 +39,19 @@ from .layers import (_dtype, dense_init, embed, init_embedding, init_mlp,
 # ==========================================================================
 # Per-layer init (vmapped over layer keys -> stacked params)
 # ==========================================================================
+def _init_attn(key, cfg: ModelConfig, dt):
+    if cfg.mla:
+        return attn_mod.init_mla(key, cfg.d_model, cfg.n_heads, cfg.mla, dt)
+    return init_attention(key, cfg.d_model, cfg.n_heads, cfg.kv_heads,
+                          cfg.resolved_head_dim, dt)
+
+
 def _init_dense_layer(key, cfg: ModelConfig):
     dt = _dtype(cfg.param_dtype)
     k1, k2 = jax.random.split(key)
     return {
         "ln1": init_rmsnorm(cfg.d_model, dt),
-        "attn": init_attention(k1, cfg.d_model, cfg.n_heads, cfg.kv_heads,
-                               cfg.resolved_head_dim, dt),
+        "attn": _init_attn(k1, cfg, dt),
         "ln2": init_rmsnorm(cfg.d_model, dt),
         "mlp": init_mlp(k2, cfg.d_model, cfg.d_ff, cfg.activation, dt),
     }
@@ -52,13 +60,15 @@ def _init_dense_layer(key, cfg: ModelConfig):
 def _init_moe_layer(key, cfg: ModelConfig):
     dt = _dtype(cfg.param_dtype)
     k1, k2 = jax.random.split(key)
+    moe = (moe_mod.init_moe_dropless(k2, cfg.d_model, cfg.moe, dt)
+           if cfg.moe.dropless else
+           moe_mod.init_moe(k2, cfg.d_model, cfg.moe.n_experts,
+                            cfg.moe.expert_ff, dt))
     return {
         "ln1": init_rmsnorm(cfg.d_model, dt),
-        "attn": init_attention(k1, cfg.d_model, cfg.n_heads, cfg.kv_heads,
-                               cfg.resolved_head_dim, dt),
+        "attn": _init_attn(k1, cfg, dt),
         "ln2": init_rmsnorm(cfg.d_model, dt),
-        "moe": moe_mod.init_moe(k2, cfg.d_model, cfg.moe.n_experts,
-                                cfg.moe.expert_ff, dt),
+        "moe": moe,
     }
 
 
@@ -133,12 +143,23 @@ def _attn_kwargs(cfg: ModelConfig, mode: str, window=None):
                 cp_axis=cfg.cp_axis)
 
 
+def _attn(p, h, cfg: ModelConfig, mode, window, positions, segment_ids,
+          span_ids):
+    if cfg.mla:
+        return attn_mod.mla_attention(
+            p, h, n_heads=cfg.n_heads, mla=cfg.mla,
+            rope_theta=cfg.rope_theta, positions=positions, mode=mode,
+            window=window, impl=cfg.attn_impl, cp_axis=cfg.cp_axis,
+            segment_ids=segment_ids, span_ids=span_ids)
+    return attention(p, h, positions=positions, segment_ids=segment_ids,
+                     span_ids=span_ids, **_attn_kwargs(cfg, mode, window))
+
+
 def _dense_block(p, x, cfg: ModelConfig, mode="causal", window=None,
                  positions=None, segment_ids=None, span_ids=None):
     h = rms_norm(p["ln1"], x, cfg.norm_eps)
-    x = x + attention(p["attn"], h, positions=positions,
-                      segment_ids=segment_ids, span_ids=span_ids,
-                      **_attn_kwargs(cfg, mode, window))
+    x = x + _attn(p["attn"], h, cfg, mode, window, positions, segment_ids,
+                  span_ids)
     h = rms_norm(p["ln2"], x, cfg.norm_eps)
     x = x + mlp(p["mlp"], h, cfg.activation)
     return x, jnp.zeros((), jnp.float32)
@@ -146,11 +167,17 @@ def _dense_block(p, x, cfg: ModelConfig, mode="causal", window=None,
 
 def _moe_block(p, x, cfg: ModelConfig, mode="causal", window=None,
                positions=None, segment_ids=None, span_ids=None):
+    """The dropless layer returns (x, aux, pairs per held expert); the
+    capacity layer (x, its load-balance loss)."""
     h = rms_norm(p["ln1"], x, cfg.norm_eps)
-    x = x + attention(p["attn"], h, positions=positions,
-                      segment_ids=segment_ids, span_ids=span_ids,
-                      **_attn_kwargs(cfg, mode, window))
+    x = x + _attn(p["attn"], h, cfg, mode, window, positions, segment_ids,
+                  span_ids)
     h = rms_norm(p["ln2"], x, cfg.norm_eps)
+    if cfg.moe.dropless:
+        out, pairs = moe_mod.moe_dropless(
+            p["moe"], h, cfg.moe,
+            token_mask=None if segment_ids is None else segment_ids >= 0)
+        return x + out, jnp.zeros((), jnp.float32), pairs
     out, aux = moe_mod.moe_ffn(p["moe"], h, top_k=cfg.moe.top_k,
                                capacity_factor=cfg.moe.capacity_factor,
                                dispatch=cfg.moe.dispatch,
@@ -189,24 +216,29 @@ def init_stack(key, cfg: ModelConfig, n_layers: int, init_fn):
 
 
 def apply_stack(stacked, x, block_fn, remat: bool, scan: bool = True):
-    """Scan x through stacked layer params, accumulating aux losses."""
+    """Scan x through stacked layer params, accumulating aux losses. A
+    block that returns (h, aux, y) has its y stacked over the layers:
+    the stack then returns (x, aux, ys)."""
     def body(carry, p_l):
         h, aux = carry
         h = constrain(h, "hidden")
         fn = jax.checkpoint(block_fn) if remat else block_fn
-        h, a = fn(p_l, h)
-        return (h, aux + a), None
+        h, a, *y = fn(p_l, h)
+        return (h, aux + a), (y[0] if y else None)
 
     if scan:
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   stacked)
+        (x, aux), ys = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)), stacked)
     else:
         aux = jnp.zeros((), jnp.float32)
         n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+        ys = []
         for i in range(n):
             p_l = jax.tree.map(lambda a: a[i], stacked)
-            (x, aux), _ = body((x, aux), p_l)
-    return x, aux
+            (x, aux), y = body((x, aux), p_l)
+            ys.append(y)
+        ys = None if ys[0] is None else jnp.stack(ys)
+    return (x, aux) if ys is None else (x, aux, ys)
 
 
 # ==========================================================================

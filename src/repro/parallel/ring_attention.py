@@ -82,8 +82,11 @@ def _partial_update(carry, q, k, v, q_pos, k_pos, mode: str,
 def ring_attention(q, k, v, q_pos, *, axis_name: str,
                    mode: str = "causal", window: Optional[int] = None,
                    q_seg=None, q_span=None) -> jax.Array:
-    """Executed INSIDE shard_map. q:[B,S_loc,H,D], k/v:[B,S_loc,Hkv,D],
-    q_pos:[B,S_loc] global positions of the local shard.
+    """Executed INSIDE shard_map. q/k:[B,S_loc,H|Hkv,D],
+    v:[B,S_loc,Hkv,Dv], q_pos:[B,S_loc] global positions of the local
+    shard. The output takes v's head dim, which may differ from q/k's
+    (latent attention: q/k 192 = 128 + 64 rope, v 128); scores scale by
+    q's.
 
     Any integer ring size is legal — jax.lax.ppermute has no
     power-of-two or head-divisibility constraint (the paper's core
@@ -110,7 +113,8 @@ def ring_attention(q, k, v, q_pos, *, axis_name: str,
 
     m = jnp.full((B, S, Hkv, G), NEG_INF, jnp.float32)
     l = jnp.zeros((B, S, Hkv, G), jnp.float32)
-    acc = jnp.zeros((B, S, Hkv, G, Dh), jnp.float32)
+    Dv = v.shape[-1]
+    acc = jnp.zeros((B, S, Hkv, G, Dv), jnp.float32)
     carry = (m, l, acc)
 
     k_cur, v_cur, kpos_cur = k, v, q_pos
@@ -136,7 +140,7 @@ def ring_attention(q, k, v, q_pos, *, axis_name: str,
                 kspan_cur = moved[-1]
     m, l, acc = carry
     o = acc / jnp.maximum(l[..., None], 1e-30)
-    return o.reshape(B, S, H, Dh).astype(q.dtype)
+    return o.reshape(B, S, H, Dv).astype(q.dtype)
 
 
 def ring_decode_attention(q1, k_cache, v_cache, local_valid, *,

@@ -8,7 +8,9 @@ fails on a CPU-only machine. The topology is described inside a fixture
 library; all such tests live in this one file so that one worker holds
 it.
 """
+import dataclasses
 import os
+import re
 
 import numpy as np
 import pytest
@@ -110,6 +112,45 @@ def test_executor_grad_step_fits_one_chip(topo):
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert live < HBM_BYTES, live
+
+
+def test_kimi_grad_step_fits_one_chip(topo, monkeypatch):
+    """The executor's span-bearing packed grad step for kimi-vl-a3b-d6e8
+    (MLA; 1 dense + 5 expert layers holding 8 of 64 experts, the grouped
+    matmuls as Pallas kernels; 20480 vocabulary rows) at a 4096-token
+    bucket on a one-chip group mesh: it compiles, and with the state it
+    trains beside it (float32 moments and gradient accumulator) it fits
+    the chip."""
+    import repro.kernels
+    # this process's backend is the CPU: compile the kernels, do not
+    # interpret them
+    monkeypatch.setattr(repro.kernels, "interpret_mode",
+                        lambda interpret=None: False)
+    base = get_config("kimi-vl-a3b")
+    cfg = base.with_(n_layers=6, vocab=20480, moe=dataclasses.replace(
+        base.moe, n_experts=8, router_experts=64))
+    ex = DHPExecutor(cfg, pool=GroupPool(topo.devices[:1]))
+    step, _, _ = ex._packed_grad_fn(0, 1, 4096, with_spans=True)
+    mesh = ex.pool.mesh_for(0, 1)
+    rep = NamedSharding(mesh, P())
+    tree = jax.eval_shape(lambda k: init_params(k, cfg),
+                          jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: _shape(a.shape, a.dtype, rep), tree)
+    seq = NamedSharding(mesh, P(None, "cp"))
+    batch = {k: _shape((1, 4096), np.dtype(dt), seq) for k, dt in (
+        ("tokens", "int32"), ("labels", "int32"), ("mask", "float32"),
+        ("positions", "int32"), ("modality_ids", "int32"),
+        ("loss_mask", "float32"), ("modality_classes", "int32"),
+        ("segment_ids", "int32"))}
+    compiled = step.lower(params, batch).compile()
+    kernels = re.findall(r"%(t?gmm)\.\d+ = ", compiled.as_text())
+    # 3 projections: forward, again under remat, gmm for dx, tgmm for dW
+    assert sorted(kernels) == ["gmm"] * 9 + ["tgmm"] * 3
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    state = 12 * sum(a.size for a in jax.tree.leaves(tree))
+    assert live + state < HBM_BYTES, (live, state)
 
 
 @pytest.mark.parametrize("last", [False, True], ids=["add", "add+divide"])
