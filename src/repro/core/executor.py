@@ -65,6 +65,7 @@ from ..data.pipeline import RaggedBatch, padded_batch
 from ..models.model import forward
 from ..obs.trace import get_tracer
 from ..training.optimizer import AdamW
+from ..training.train_step import token_nll
 from .group_pool import GroupPool
 from .packing import MODALITY_CLASSES, flatten_group
 from .scheduler import ExecutionPlan
@@ -117,20 +118,12 @@ def accumulate_grads(acc, out, w, total=None):
             acc[2])
 
 
-def _token_nll(logits, labels):
-    """Per-position next-token NLL (no masking applied)."""
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    return logz - gold
-
-
 def _tree_nbytes(tree) -> int:
     return sum(a.nbytes for a in jax.tree.leaves(tree))
 
 
 def _masked_nll(logits, labels, mask):
-    nll = _token_nll(logits, labels) * mask
+    nll = token_nll(logits, labels) * mask
     return nll.sum(), mask.sum()
 
 
@@ -222,7 +215,7 @@ class DHPExecutor:
                 return s / jnp.maximum(c, 1.0)
 
             def span_loss(logits, batch):
-                nll = _token_nll(logits, batch["labels"])
+                nll = token_nll(logits, batch["labels"])
                 lm = batch["loss_mask"]
                 s = jax.lax.psum((nll * lm).sum(), "cp")
                 c = jax.lax.psum(lm.sum(), "cp")

@@ -296,12 +296,14 @@ def test_reduced_holds_a_share():
 # the dense path is unchanged
 # --------------------------------------------------------------------------
 #: sha256 of the lowered `fwd_bwd` of the reduced internvl3-2b packed grad
-#: step (256-token bucket, one device), causal and span-bearing, and the
-#: planner coefficients of internvl3-2b: recorded before MLA and the
-#: dropless expert layer were added
+#: step (256-token bucket, one device), causal and span-bearing: recorded
+#: after the one-hot gold logit, whose equality with the gather it
+#: replaced `tests/test_token_nll.py` checks. The planner coefficients of
+#: internvl3-2b: recorded before MLA and the dropless expert layer were
+#: added
 DENSE_GRAD_STEP_SHA256 = {
-    False: "6c69017e02f1b5262cd34eab2fc9a5a4cdeeb35d34f9636fa34fb788bdd66568",
-    True: "a0621a4897f4bccf86ab52d5954a1725605d0ae2868acbf8fd9bb87209c0e3e4",
+    False: "8da4a1eec685cfbc0244091a07e672f7f5e150ffd9c99b9050479589f7823ce0",
+    True: "6d3d99edceba094173ff510404d1b741acd703af380cbdcf41c9fd724a58cc3b",
 }
 DENSE_COEFFS = {
     28: {"a1": 2.9108629441624364e-09, "a2": 0.00010444446538071066,

@@ -31,6 +31,8 @@ from repro.models.model import init_params
 #: width 128 (the kernels take KV already expanded to every head)
 BH, S, D = 12, 4096, 128
 HBM_BYTES = 16 * 2**30          # one v5e chip
+#: the bucket of the internvl3-2b grad-step compiles
+GRAD_BUCKET = 2048
 
 
 @pytest.fixture(scope="module")
@@ -89,13 +91,14 @@ def test_flash_attention_packed_flat_compiles(one_chip, with_spans):
     _assert_kernel(lowered.compile())
 
 
-def test_executor_grad_step_fits_one_chip(topo):
-    """The executor's span-bearing packed grad step at internvl3-2b
-    widths (one layer, 2048-token bucket) on a one-chip group mesh."""
+def _dense_grad_step(topo, with_spans):
+    """The executor's packed grad step at internvl3-2b widths (one layer,
+    151674 vocabulary rows), compiled for a one-chip group mesh."""
     cfg = get_config("internvl3-2b").with_(family="dense", vlm=None,
                                            n_layers=1)
     ex = DHPExecutor(cfg, pool=GroupPool(topo.devices[:1]))
-    step, _, _ = ex._packed_grad_fn(0, 1, 2048, with_spans=True)
+    step, _, _ = ex._packed_grad_fn(0, 1, GRAD_BUCKET,
+                                    with_spans=with_spans)
     mesh = ex.pool.mesh_for(0, 1)
     rep = NamedSharding(mesh, P())
     params = jax.tree.map(
@@ -103,15 +106,40 @@ def test_executor_grad_step_fits_one_chip(topo):
         jax.eval_shape(lambda k: init_params(k, cfg),
                        jax.random.PRNGKey(0)))
     seq = NamedSharding(mesh, P(None, "cp"))
-    batch = {k: _shape((1, 2048), np.dtype(dt), seq) for k, dt in (
-        ("tokens", "int32"), ("labels", "int32"), ("mask", "float32"),
-        ("positions", "int32"), ("modality_ids", "int32"),
-        ("loss_mask", "float32"), ("modality_classes", "int32"),
-        ("segment_ids", "int32"))}
-    mem = step.lower(params, batch).compile().memory_analysis()
+    keys = [("tokens", "int32"), ("labels", "int32"), ("mask", "float32"),
+            ("positions", "int32")]
+    if with_spans:
+        keys += [("modality_ids", "int32"), ("loss_mask", "float32"),
+                 ("modality_classes", "int32")]
+    batch = {k: _shape((1, GRAD_BUCKET), np.dtype(dt), seq)
+             for k, dt in keys + [("segment_ids", "int32")]}
+    return cfg, step.lower(params, batch).compile()
+
+
+def test_executor_grad_step_fits_one_chip(topo):
+    """The executor's span-bearing packed grad step at internvl3-2b
+    widths (one layer, 2048-token bucket) on a one-chip group mesh."""
+    _, compiled = _dense_grad_step(topo, with_spans=True)
+    mem = compiled.memory_analysis()
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert live < HBM_BYTES, live
+
+
+@pytest.mark.parametrize("with_spans", [False, True],
+                         ids=["causal", "spans"])
+def test_grad_step_keeps_the_logits_tiled(topo, with_spans):
+    """At a vocabulary that is not a multiple of 128 (151674), the
+    compiled grad step holds no flat [bucket x V] buffer and no loop
+    over the logits: a gather's scatter gradient ran on the flattened
+    logits, with a relayout loop each way between flat and tiled."""
+    cfg, compiled = _dense_grad_step(topo, with_spans)
+    text = compiled.as_text()
+    assert cfg.vocab % 128
+    assert not re.search(r"\w+\[%d\]" % (GRAD_BUCKET * cfg.vocab), text)
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert not [line for line in loops
+                if f"{GRAD_BUCKET},{cfg.vocab}]" in line], loops
 
 
 def test_kimi_grad_step_fits_one_chip(topo, monkeypatch):
